@@ -20,6 +20,7 @@ import json
 import os
 import platform
 import time
+from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -1368,6 +1369,9 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="persist rows as JSON (the BENCH_*.json trajectory)")
     args, _ = ap.parse_known_args()
+    from repro.device import use_compile_cache
+
+    use_compile_cache(Path(__file__).resolve().parents[1])
     REPEAT_OVERRIDE = args.repeat
     names = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")

@@ -1216,13 +1216,14 @@ class TGI:
                 np.stack([d.attrs for d in layers]),
                 tmask,
             )
+            # (P, S, T[, K]): timepoint j is axis 2
             v, p, a = np.asarray(v), np.asarray(p), np.asarray(a)
             states = []
             for j, d in enumerate(ev_deltas):
                 st = base.copy()
-                st.valid = v[..., j] != 0
-                st.present = p[..., j]
-                st.attrs = a[..., j]
+                st.valid = v[:, :, j]
+                st.present = p[:, :, j]
+                st.attrs = a[:, :, j]
                 if d is not None:
                     st.e_src, st.e_dst, st.e_op, st.e_val = delta_mod._edge_sum(
                         base, d)

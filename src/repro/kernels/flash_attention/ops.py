@@ -6,14 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.device import interpret
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "blk_q", "blk_k"))
@@ -35,6 +29,6 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=0,
     kpos = jnp.pad(k_pos.astype(jnp.int32), (0, pk), constant_values=-1)
     out = flash_attention_pallas(
         qp, kp, vp, qpos, kpos, causal=causal, window=window,
-        blk_q=blk_q, blk_k=blk_k, interpret=not _on_tpu()
+        blk_q=blk_q, blk_k=blk_k, interpret=interpret()
     )
     return out[:, :, :Sq]

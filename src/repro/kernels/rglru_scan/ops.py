@@ -6,14 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.device import interpret
 from repro.kernels.rglru_scan.rglru_scan import CHUNK, TILE_W, rglru_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "tile_w"))
@@ -29,5 +23,5 @@ def rglru(log_a, b, chunk=CHUNK, tile_w=TILE_W):
     pw = (-W) % tile_w
     la = jnp.pad(log_a.astype(jnp.float32), ((0, 0), (0, ps), (0, pw)))
     bb = jnp.pad(b.astype(jnp.float32), ((0, 0), (0, ps), (0, pw)))
-    h = rglru_pallas(la, bb, chunk=chunk, tile_w=tile_w, interpret=not _on_tpu())
+    h = rglru_pallas(la, bb, chunk=chunk, tile_w=tile_w, interpret=interpret())
     return h[:, :S, :W]

@@ -1,19 +1,11 @@
 """Jit'd wrapper for the temporal connected-components kernel: node-axis
-padding, interpret-mode fallback (CPU) / native lowering (TPU)."""
+padding."""
 from __future__ import annotations
 
-import jax
-
+from repro.device import interpret
 from repro.kernels.temporal_cc import ref
 from repro.kernels.temporal_cc.temporal_cc import cc_pallas
 from repro.kernels.temporal_pagerank.ops import pad_nodes
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def temporal_cc(adj, active, iters: int = 32, use_pallas: bool = True):
@@ -22,12 +14,11 @@ def temporal_cc(adj, active, iters: int = 32, use_pallas: bool = True):
     propagation rounds; -1 on inactive nodes).
 
     adj: (T, N, N) symmetric 0/1 adjacency; active: (T, N) mask.
-    Accepts numpy or jnp.  Runs the Pallas kernel in interpret mode
-    off-TPU and natively on TPU, or the pure-jnp reference with
-    ``use_pallas=False``.
+    Accepts numpy or jnp.  Runs the Pallas kernel, or the pure-jnp
+    reference with ``use_pallas=False``.
     """
     if not use_pallas:
         return ref.cc_ref(adj, active, iters=iters)
     padded, act, N = pad_nodes(adj, active)
-    out = cc_pallas(padded, act, iters=iters, interpret=not _on_tpu())
+    out = cc_pallas(padded, act, iters=iters, interpret=interpret())
     return out[:, :N]

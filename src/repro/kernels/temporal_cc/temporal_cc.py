@@ -12,8 +12,8 @@ bit-identical (int32) by construction.
 Grid: (T,).  Blocks are (1, N, N) adjacency + (1, N) activity per
 timepoint, N a multiple of 128 (ops.py pads).  Inactive (and padded)
 nodes take label -1 and never win a min.  Validated in interpret mode
-against ref.cc_ref (CPU container); on TPU the same pallas_call lowers
-natively.
+against ref.cc_ref.  It does not lower for a TPU yet: the (1, N) activity
+block breaks the (8, 128) block rule.
 """
 from __future__ import annotations
 

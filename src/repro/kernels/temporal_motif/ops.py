@@ -1,19 +1,12 @@
-"""Jit'd wrapper for the temporal motif kernel: node-axis padding,
-interpret-mode fallback (CPU) / native lowering (TPU)."""
+"""Jit'd wrapper for the temporal motif kernel: node-axis padding to the
+kernel's tile."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.device import interpret
 from repro.kernels.temporal_motif import ref
-from repro.kernels.temporal_motif.temporal_motif import LANE, motif_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+from repro.kernels.temporal_motif.temporal_motif import block_for, motif_pallas
 
 
 def temporal_motif(adj, use_pallas: bool = True):
@@ -21,15 +14,14 @@ def temporal_motif(adj, use_pallas: bool = True):
     dense adjacency.
 
     adj: (T, N, N) symmetric 0/1 adjacency (zero diagonal).  Accepts
-    numpy or jnp.  Runs the Pallas kernel in interpret mode off-TPU and
-    natively on TPU, or the pure-jnp reference with ``use_pallas=False``.
+    numpy or jnp.  Runs the Pallas kernel, or the pure-jnp reference
+    with ``use_pallas=False``.
     """
     if not use_pallas:
         return ref.motif_ref(adj)
-    adj = jnp.asarray(adj, jnp.float32)
+    adj = jnp.asarray(adj)
     N = adj.shape[-1]
-    pad = (-N) % LANE
+    pad = (-N) % block_for(N)
     if pad:
         adj = jnp.pad(adj, ((0, 0), (0, pad), (0, pad)))
-    out = motif_pallas(adj, interpret=not _on_tpu())
-    return out[:, :N]
+    return motif_pallas(adj, interpret=interpret())[:, :N]

@@ -1,23 +1,15 @@
 """Jit'd wrapper for the temporal PageRank kernel: node-axis padding to
-the 128-lane tile, interpret-mode fallback (CPU container) / native
-lowering (TPU)."""
+the 128-lane tile."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.device import interpret
 from repro.kernels.temporal_pagerank import ref
 from repro.kernels.temporal_pagerank.temporal_pagerank import (
     LANE,
     pagerank_pallas,
 )
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def pad_nodes(adj, active):
@@ -39,12 +31,11 @@ def temporal_pagerank(adj, active, damping: float = 0.85, iters: int = 20,
 
     adj: (T, N, N) symmetric 0/1 adjacency (zero diagonal);
     active: (T, N) present mask.  Accepts numpy or jnp.  Runs the Pallas
-    kernel in interpret mode off-TPU and natively on TPU, or the pure-jnp
-    reference with ``use_pallas=False``.
+    kernel, or the pure-jnp reference with ``use_pallas=False``.
     """
     if not use_pallas:
         return ref.pagerank_ref(adj, active, damping=damping, iters=iters)
     padded, act, N = pad_nodes(adj, active)
     out = pagerank_pallas(padded, act, damping=damping, iters=iters,
-                          interpret=not _on_tpu())
+                          interpret=interpret())
     return out[:, :N]

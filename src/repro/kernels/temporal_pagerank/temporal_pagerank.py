@@ -13,8 +13,8 @@ pair-table gather/scatter formulation; both are parity-tested).
 
 Grid: (T,).  Blocks are (1, N, N) adjacency + (1, N) activity per
 timepoint, N a multiple of 128 (ops.py pads; padded nodes are inactive).
-Validated in interpret mode against ref.pagerank_ref (CPU container); on
-TPU the same pallas_call lowers natively.
+Validated in interpret mode against ref.pagerank_ref.  It does not lower
+for a TPU yet: the (1, N) activity block breaks the (8, 128) block rule.
 """
 from __future__ import annotations
 

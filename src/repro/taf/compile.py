@@ -60,6 +60,7 @@ MIN_FUSE_T = 16
 
 # dense-adjacency budget (elements) for the triangle program: T*N^2 above
 # this falls back to the staged path rather than materializing the stack
+# (the tiled motif kernel itself compiles at any N)
 DENSE_BUDGET = 64_000_000
 
 ENABLED = True
@@ -293,12 +294,10 @@ class TrianglesOp(FusedOp):
 
         u, v = arrs["edge_u"], arrs["edge_v"]
         N, T = act.shape
-        live_t = live.T  # (T, E)
-        adj = (jnp.zeros((T, N, N), jnp.float32)
+        live_t = live.T.astype(jnp.bfloat16)  # (T, E), 0/1 exact
+        adj = (jnp.zeros((T, N, N), jnp.bfloat16)
                .at[:, u, v].max(live_t).at[:, v, u].max(live_t))
-        # pallas natively on TPU, the identical jnp math elsewhere
-        tri = motif_ops.temporal_motif(adj, use_pallas=motif_ops._on_tpu())
-        return tri.T  # (N, T) int32
+        return motif_ops.temporal_motif(adj).T  # (N, T) int32
 
 
 class FusedScalarOp:

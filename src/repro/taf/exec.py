@@ -23,6 +23,7 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.taf import replay
 from repro.taf.son import SoN, build_son
@@ -52,12 +53,8 @@ def clear_device_caches() -> None:
 
 
 def make_worker_mesh():
-    n = len(jax.devices())
-    try:  # axis_types landed after jax 0.4.x; plain mesh is equivalent here
-        return jax.make_mesh((n,), ("workers",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        return jax.make_mesh((n,), ("workers",))
+    return jax.make_mesh((len(jax.devices()),), ("workers",),
+                         axis_types=(AxisType.Auto,))
 
 
 def parallel_fetch(tgi, t0: int, t1: int, c: int = 1) -> SoN:
@@ -88,12 +85,15 @@ def sharded_node_compute(son: SoN, kernel: Callable, mesh=None,
     """
     mesh = mesh or make_worker_mesh()
     W = mesh.devices.size
-    okey = (replay.operand_key(son), W)
+    spec = P("workers")
+    okey = (replay.operand_key(son), tuple(int(d.id) for d in mesh.devices.flat))
     operands = _OPERAND_CACHE.get(okey, owner=son)
     if operands is None:
         STATS["operand_transfers"] += 1
         pads = son.padded_events()
-        operands = tuple(jnp.asarray(a) for a in (
+        # each device receives only its node block (no staging on device 0)
+        shard = NamedSharding(mesh, spec)
+        operands = tuple(jax.device_put(a, shard) for a in (
             _pad_to_multiple(son.init_present.astype(np.int32), W, -1),
             _pad_to_multiple(son.init_attrs, W, -1),
             _pad_to_multiple(pads["t"], W, np.iinfo(np.int64).max),
@@ -104,19 +104,12 @@ def sharded_node_compute(son: SoN, kernel: Callable, mesh=None,
     else:
         STATS["operand_cache_hits"] += 1
 
-    from jax.sharding import PartitionSpec as P
-
-    spec = P("workers")
-    shard_map = jax.shard_map if hasattr(jax, "shard_map") else None
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map  # jax<0.7 fallback
-
     fkey = (getattr(kernel, "compile_key", None) or id(kernel),
             tuple(int(d.id) for d in mesh.devices.flat),
             tuple((a.shape, str(a.dtype)) for a in operands))
     fn = _FN_CACHE.get(fkey)
     if fn is None:
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda *a: kernel(*a),
             mesh=mesh,
             in_specs=(spec,) * 5,

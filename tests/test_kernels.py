@@ -294,7 +294,7 @@ def test_temporal_cc_matches_ref(seed, N):
         assert (g[~on] == -1).all()
 
 
-@pytest.mark.parametrize("seed,N", [(5, 40), (6, 130)])
+@pytest.mark.parametrize("seed,N", [(5, 40), (6, 130), (7, 700)])
 def test_temporal_motif_matches_ref_and_bruteforce(seed, N):
     from repro.kernels.temporal_motif import ops as mo_ops
     from repro.kernels.temporal_motif import ref as mo_ref

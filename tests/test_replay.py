@@ -351,11 +351,25 @@ def test_timeslice_multi_matches_scalar_slices(store_setup):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_get_snapshots_matches_single_snapshots(store_setup, use_kernel):
+@pytest.mark.parametrize("use_kernel,clustered", [
+    (False, False), (True, False), (True, True)],
+    ids=["False", "True", "clustered-True"])
+def test_get_snapshots_matches_single_snapshots(store_setup, use_kernel,
+                                                clustered):
     store, t0, t1 = store_setup
     tgi = store.tgi
-    ts = np.linspace(t0, t1, 5).astype(np.int64).tolist()
+    if clustered:
+        # 8 timepoints 5 ticks apart: (span, leaf) groups with T > 1 and
+        # T > K, so the time-batched kernel branch folds them
+        tm = (t0 + t1) // 2
+        ts = [tm + 5 * j for j in range(8)]
+        with tgi.read_guard() as view:
+            sis = [tgi._span_index(t, view) for t in ts]
+            groups = {(si.span.tsid, tgi._leaf_for(si, t))
+                      for si, t in zip(sis, ts)}
+        assert len(groups) < len(ts) and len(ts) > store.cfg.n_attrs
+    else:
+        ts = np.linspace(t0, t1, 5).astype(np.int64).tolist()
     tgi.invalidate_caches()
     want = []
     for t in ts:

@@ -1,0 +1,109 @@
+"""The main path's kernels and fused programs compile for a TPU v5e.
+
+Nothing runs: each test compiles for a described (not attached) v5e
+chip, which raises what the chip's compiler would raise — block shapes
+off the (8, 128) tiling, layouts Mosaic cannot lower, blocks that
+overflow VMEM.  Interpret mode on the CPU cannot see any of these.  The
+topology is described inside a fixture, never at import: only one
+process at a time may load the TPU compiler library.
+"""
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.delta_overlay.delta_overlay import (
+    overlay_batch_pallas,
+    overlay_pallas,
+)
+from repro.kernels.delta_overlay.ops import _tile_s
+from repro.kernels.temporal_motif.temporal_motif import block_for, motif_pallas
+from repro.taf import compile as tc
+
+# the overlay at a deployment's partition tile: h layers, P partitions of
+# S slots, K attribute keys, T timepoints per (span, leaf) group
+H, P, S, K, T = 6, 8, 4096, 4, 16
+
+# chip_smoke.py's fused-analytics operand at 250k events, seed 0 (window
+# = last quarter): N members, Emax events per member row and pair-table
+# rows as the smoke prints them; flips per pair row and canonical edges
+# (at most half the pair rows) are upper estimates; 64 timepoints
+SMOKE_N, SMOKE_EMAX, SMOKE_PAIRS, SMOKE_FLIPS, SMOKE_E, SMOKE_T = (
+    25_336, 374, 213_924, 4, 107_000, 64)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_overlay_kernel_lowers_natively(spec):
+    tile = _tile_s(S, 4 * (H + 1) * P * (2 + K))
+    compiled = _compile(
+        lambda v, p, a: overlay_pallas(v, p, a, tile, interpret=False),
+        spec((H, P, S), jnp.int32), spec((H, P, S), jnp.int32),
+        spec((H, K, P, S), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_overlay_batch_kernel_lowers_natively(spec):
+    h = H + T  # shared path layers + one eventlist layer per timepoint
+    tile = _tile_s(S, 4 * (h + T) * P * (2 + K))
+    compiled = _compile(
+        lambda m, v, p, a: overlay_batch_pallas(v, p, a, m, tile,
+                                                interpret=False),
+        spec((h, T), jnp.int32), spec((h, P, S), jnp.int32),
+        spec((h, P, S), jnp.int32), spec((h, K, P, S), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_motif_kernel_lowers_at_largest_fused_n(spec):
+    """The fused triangle path sends the kernel any T * N^2 within
+    DENSE_BUDGET; at T=16 that is N=2000, padded to the kernel's tile."""
+    t = 16
+    n = math.isqrt(tc.DENSE_BUDGET // t)
+    assert tc._budget_miss(tc.triangles(), range(n), t) is None
+    assert tc._budget_miss(tc.triangles(), range(n + 1), t) is not None
+    n_pad = -(-n // block_for(n)) * block_for(n)
+    compiled = _compile(lambda a: motif_pallas(a, interpret=False),
+                        spec((t, n_pad, n_pad), jnp.bfloat16))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_pagerank_program_lowers_at_smoke_shapes(spec):
+    i32 = jnp.int32
+    node = {k: spec((SMOKE_N, SMOKE_EMAX), i32)
+            for k in ("ev_t", "ev_kind", "ev_key", "ev_val")}
+    node["init_present"] = spec((SMOKE_N,), i32)
+    node["init_attrs"] = spec((SMOKE_N, K), i32)
+    edge = {"flip_t": spec((SMOKE_PAIRS, SMOKE_FLIPS), i32),
+            "flip_s": spec((SMOKE_PAIRS, SMOKE_FLIPS), i32),
+            "base": spec((SMOKE_PAIRS,), i32),
+            "edge_valid": spec((SMOKE_E,), jnp.float32)}
+    for k in ("edge_u", "edge_v", "pair_a", "pair_b"):
+        edge[k] = spec((SMOKE_E,), i32)
+    for k in ("frow", "fcol", "feid"):
+        edge[k] = spec((2 * SMOKE_E,), i32)
+    prog = tc._build_series_program(tc.pagerank())
+    compiled = prog.lower(node, edge, spec((SMOKE_T,), i32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
