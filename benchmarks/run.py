@@ -6,22 +6,18 @@ behavior per figure (parallel-fetch speedup, partition-size trade-off,
 incremental-vs-version computation, index-size ordering).  BENCH_SCALE
 env (default 1.0) scales event counts.
 
-  PYTHONPATH=src python -m benchmarks.run [--only fig11,...]
-      [--repeat N] [--json PATH]
+  PYTHONPATH=src python -m benchmarks.run [--only fig11,...] [--repeat N]
 
-``--json PATH`` additionally persists every row as JSON (the BENCH_*.json
-perf trajectory committed per PR); ``--repeat`` overrides each bench's
-default repeat count (1 = CI smoke mode).
+``--repeat`` overrides each bench's default repeat count (1 = CI smoke
+mode).
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import platform
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, Optional
 
 import numpy as np
 
@@ -29,7 +25,6 @@ SCALE = float(os.environ.get("BENCH_SCALE", "1.0"))
 N_EVENTS = int(12_000 * SCALE)
 
 REPEAT_OVERRIDE: Optional[int] = None  # set by --repeat
-RESULTS: List[Dict] = []  # every _row lands here for --json
 
 
 def _timeit(fn, repeat=3):
@@ -43,8 +38,6 @@ def _timeit(fn, repeat=3):
 
 
 def _row(name, us, derived=""):
-    RESULTS.append({"name": name, "us": round(float(us), 1),
-                    "derived": derived})
     print(f"{name},{us:.1f},{derived}", flush=True)
 
 
@@ -1366,8 +1359,6 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated bench names")
     ap.add_argument("--repeat", type=int, default=None,
                     help="override per-bench repeat counts (1 = smoke mode)")
-    ap.add_argument("--json", default=None, metavar="PATH",
-                    help="persist rows as JSON (the BENCH_*.json trajectory)")
     args, _ = ap.parse_known_args()
     from repro.device import use_compile_cache
 
@@ -1377,21 +1368,6 @@ def main() -> None:
     print("name,us_per_call,derived")
     for n in names:
         BENCHES[n]()
-    if args.json:
-        payload = {
-            "meta": {
-                "benches": names,
-                "n_events": N_EVENTS,
-                "scale": SCALE,
-                "repeat_override": REPEAT_OVERRIDE,
-                "python": platform.python_version(),
-                "platform": platform.platform(),
-            },
-            "rows": RESULTS,
-        }
-        with open(args.json, "w") as f:
-            json.dump(payload, f, indent=1)
-        print(f"# wrote {len(RESULTS)} rows -> {args.json}", flush=True)
 
 
 if __name__ == "__main__":

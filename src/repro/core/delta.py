@@ -31,6 +31,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import trace
+
 SENTINEL = np.int32(2**31 - 1)
 
 # the stored micro-delta schema (one source of truth for serialization,
@@ -110,45 +112,47 @@ def _edge_key(src, dst):
 
 def _edge_sum(a: Delta, b: Delta, cap: Optional[int] = None):
     """Sorted last-wins merge of edge runs (b wins)."""
-    na = int((a.e_src != SENTINEL).sum())
-    nb = int((b.e_src != SENTINEL).sum())
-    src = np.concatenate([a.e_src[:na], b.e_src[:nb]])
-    dst = np.concatenate([a.e_dst[:na], b.e_dst[:nb]])
-    op = np.concatenate([a.e_op[:na], b.e_op[:nb]])
-    val = np.concatenate([a.e_val[:na], b.e_val[:nb]])
-    prio = np.concatenate([np.zeros(na, np.int8), np.ones(nb, np.int8)])
-    key = _edge_key(src, dst)
-    order = np.lexsort((prio, key))
-    key, src, dst, op, val = key[order], src[order], dst[order], op[order], val[order]
-    # keep last of each key; inherit attr from the earlier run when the
-    # later one leaves it unset and keeps the edge present
-    last = np.ones(len(key), bool)
-    if len(key) > 1:
-        last[:-1] = key[1:] != key[:-1]
-    # attribute inheritance within equal-key runs (at most 2 entries)
-    if len(key) > 1:
-        same_prev = key[1:] == key[:-1]
-        inherit = same_prev & (val[1:] == -1) & (op[1:] == 1)
-        val[1:][inherit] = val[:-1][inherit]
-    src, dst, op, val = src[last], dst[last], op[last], val[last]
-    n = len(src)
-    cap = cap if cap is not None else max(n, 1)
-    cap = max(cap, n)
-    out = (
-        np.full(cap, SENTINEL, np.int32),
-        np.full(cap, SENTINEL, np.int32),
-        np.zeros(cap, np.int8),
-        np.full(cap, -1, np.int32),
-    )
-    out[0][:n], out[1][:n], out[2][:n], out[3][:n] = src, dst, op, val
-    return out
+    with trace.span("delta.edge_sum"):
+        na = int((a.e_src != SENTINEL).sum())
+        nb = int((b.e_src != SENTINEL).sum())
+        src = np.concatenate([a.e_src[:na], b.e_src[:nb]])
+        dst = np.concatenate([a.e_dst[:na], b.e_dst[:nb]])
+        op = np.concatenate([a.e_op[:na], b.e_op[:nb]])
+        val = np.concatenate([a.e_val[:na], b.e_val[:nb]])
+        prio = np.concatenate([np.zeros(na, np.int8), np.ones(nb, np.int8)])
+        key = _edge_key(src, dst)
+        order = np.lexsort((prio, key))
+        key, src, dst, op, val = key[order], src[order], dst[order], op[order], val[order]
+        # keep last of each key; inherit attr from the earlier run when the
+        # later one leaves it unset and keeps the edge present
+        last = np.ones(len(key), bool)
+        if len(key) > 1:
+            last[:-1] = key[1:] != key[:-1]
+        # attribute inheritance within equal-key runs (at most 2 entries)
+        if len(key) > 1:
+            same_prev = key[1:] == key[:-1]
+            inherit = same_prev & (val[1:] == -1) & (op[1:] == 1)
+            val[1:][inherit] = val[:-1][inherit]
+        src, dst, op, val = src[last], dst[last], op[last], val[last]
+        n = len(src)
+        cap = cap if cap is not None else max(n, 1)
+        cap = max(cap, n)
+        out = (
+            np.full(cap, SENTINEL, np.int32),
+            np.full(cap, SENTINEL, np.int32),
+            np.zeros(cap, np.int8),
+            np.full(cap, -1, np.int32),
+        )
+        out[0][:n], out[1][:n], out[2][:n], out[3][:n] = src, dst, op, val
+        return out
 
 
 def delta_sum(a: Delta, b: Delta, ecap: Optional[int] = None) -> Delta:
     """Paper Def. 4: Δs = a + b (b's components win on id collision)."""
-    valid, present, attrs = _node_sum(a, b)
-    e_src, e_dst, e_op, e_val = _edge_sum(a, b, ecap)
-    return Delta(valid, present, attrs, e_src, e_dst, e_op, e_val)
+    with trace.span("delta.delta_sum"):
+        valid, present, attrs = _node_sum(a, b)
+        e_src, e_dst, e_op, e_val = _edge_sum(a, b, ecap)
+        return Delta(valid, present, attrs, e_src, e_dst, e_op, e_val)
 
 
 def delta_intersection(a: Delta, b: Delta) -> Delta:

@@ -13,6 +13,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core import delta as delta_mod
 from repro.core.delta import SENTINEL, Delta
 from repro.core.events import (
@@ -221,72 +222,75 @@ def events_to_delta(ev: EventLog, smap: SlotMap, K: int,
     the paper's events are post-state diffs; we mark present=1 (an attr
     set implies the node exists).
     """
-    d = Delta.empty(smap.n_parts, smap.psize, K, ecap=max(int(((ev.kind == EDGE_ADD) | (ev.kind == EDGE_DEL) | (ev.kind == EATTR_SET)).sum()), 1))
-    if not len(ev):
+    with trace.span("snapshot.events_to_delta"):
+        n_edge_ev = int(((ev.kind == EDGE_ADD) | (ev.kind == EDGE_DEL)
+                         | (ev.kind == EATTR_SET)).sum())
+        d = Delta.empty(smap.n_parts, smap.psize, K, ecap=max(n_edge_ev, 1))
+        if not len(ev):
+            return d
+        # --- nodes ---
+        m = (ev.kind == NODE_ADD) | (ev.kind == NODE_DEL) | (ev.kind == NATTR_SET)
+        if m.any():
+            nids = ev.src[m]
+            kinds = ev.kind[m]
+            keys = ev.key[m]
+            vals = ev.val[m]
+            pid, slot, found = smap.lookup(nids)
+            assert found.all(), "event touches node outside timespan slot map"
+            # chronological apply (vectorized last-wins per (node) for
+            # presence, per (node,key) for attrs)
+            pres_m = kinds != NATTR_SET
+            if pres_m.any():
+                n2, p2, s2 = nids[pres_m], pid[pres_m], slot[pres_m]
+                ops = (kinds[pres_m] == NODE_ADD).astype(np.int8)
+                _, last = np.unique(n2[::-1], return_index=True)
+                last = len(n2) - 1 - last
+                d.valid[p2[last], s2[last]] = True
+                d.present[p2[last], s2[last]] = ops[last]
+            at_m = kinds == NATTR_SET
+            if at_m.any():
+                n2, p2, s2 = nids[at_m], pid[at_m], slot[at_m]
+                k2, v2 = keys[at_m].astype(np.int64), vals[at_m]
+                ck = n2.astype(np.int64) * 64 + k2
+                _, last = np.unique(ck[::-1], return_index=True)
+                last = len(ck) - 1 - last
+                newly = ~d.valid[p2[last], s2[last]]
+                d.valid[p2[last], s2[last]] = True
+                # attr-set implies existence unless an explicit later delete
+                d.present[p2[last], s2[last]] = np.where(
+                    newly, 1, d.present[p2[last], s2[last]]
+                )
+                d.attrs[p2[last], s2[last], k2[last].astype(np.int32)] = v2[last]
+        # --- edges ---
+        m = (ev.kind == EDGE_ADD) | (ev.kind == EDGE_DEL) | (ev.kind == EATTR_SET)
+        if m.any():
+            src, dst, kinds, vals = ev.src[m], ev.dst[m], ev.kind[m], ev.val[m]
+            key = pack_edge_key(src, dst)
+            _, last = np.unique(key[::-1], return_index=True)
+            last = np.sort(len(key) - 1 - last)
+            src, dst, kinds, vals = src[last], dst[last], kinds[last], vals[last]
+            # mirror to both endpoints (see GraphState.to_delta)
+            m_src = np.concatenate([src, dst])
+            m_dst = np.concatenate([dst, src])
+            m_kinds = np.concatenate([kinds, kinds])
+            m_vals = np.concatenate([vals, vals])
+            pid, slot, found = smap.lookup(m_src)
+            assert found.all()
+            gslot = pid.astype(np.int64) * smap.psize + slot
+            order = np.lexsort((m_dst, gslot))
+            n = len(order)
+            need = n
+            if need > len(d.e_src):
+                pad = need - len(d.e_src)
+                d.e_src = np.r_[d.e_src, np.full(pad, SENTINEL, np.int32)]
+                d.e_dst = np.r_[d.e_dst, np.full(pad, SENTINEL, np.int32)]
+                d.e_op = np.r_[d.e_op, np.zeros(pad, np.int8)]
+                d.e_val = np.r_[d.e_val, np.full(pad, -1, np.int32)]
+            d.e_src[:n] = gslot[order].astype(np.int32)
+            d.e_dst[:n] = m_dst[order]
+            d.e_op[:n] = (m_kinds[order] != EDGE_DEL).astype(np.int8)
+            d.e_val[:n] = m_vals[order]
         return d
-    # --- nodes ---
-    m = (ev.kind == NODE_ADD) | (ev.kind == NODE_DEL) | (ev.kind == NATTR_SET)
-    if m.any():
-        nids = ev.src[m]
-        kinds = ev.kind[m]
-        keys = ev.key[m]
-        vals = ev.val[m]
-        pid, slot, found = smap.lookup(nids)
-        assert found.all(), "event touches node outside timespan slot map"
-        # chronological apply (vectorized last-wins per (node) for
-        # presence, per (node,key) for attrs)
-        pres_m = kinds != NATTR_SET
-        if pres_m.any():
-            n2, p2, s2 = nids[pres_m], pid[pres_m], slot[pres_m]
-            ops = (kinds[pres_m] == NODE_ADD).astype(np.int8)
-            _, last = np.unique(n2[::-1], return_index=True)
-            last = len(n2) - 1 - last
-            d.valid[p2[last], s2[last]] = True
-            d.present[p2[last], s2[last]] = ops[last]
-        at_m = kinds == NATTR_SET
-        if at_m.any():
-            n2, p2, s2 = nids[at_m], pid[at_m], slot[at_m]
-            k2, v2 = keys[at_m].astype(np.int64), vals[at_m]
-            ck = n2.astype(np.int64) * 64 + k2
-            _, last = np.unique(ck[::-1], return_index=True)
-            last = len(ck) - 1 - last
-            newly = ~d.valid[p2[last], s2[last]]
-            d.valid[p2[last], s2[last]] = True
-            # attr-set implies existence unless an explicit later delete
-            d.present[p2[last], s2[last]] = np.where(
-                newly, 1, d.present[p2[last], s2[last]]
-            )
-            d.attrs[p2[last], s2[last], k2[last].astype(np.int32)] = v2[last]
-    # --- edges ---
-    m = (ev.kind == EDGE_ADD) | (ev.kind == EDGE_DEL) | (ev.kind == EATTR_SET)
-    if m.any():
-        src, dst, kinds, vals = ev.src[m], ev.dst[m], ev.kind[m], ev.val[m]
-        key = pack_edge_key(src, dst)
-        _, last = np.unique(key[::-1], return_index=True)
-        last = np.sort(len(key) - 1 - last)
-        src, dst, kinds, vals = src[last], dst[last], kinds[last], vals[last]
-        # mirror to both endpoints (see GraphState.to_delta)
-        m_src = np.concatenate([src, dst])
-        m_dst = np.concatenate([dst, src])
-        m_kinds = np.concatenate([kinds, kinds])
-        m_vals = np.concatenate([vals, vals])
-        pid, slot, found = smap.lookup(m_src)
-        assert found.all()
-        gslot = pid.astype(np.int64) * smap.psize + slot
-        order = np.lexsort((m_dst, gslot))
-        n = len(order)
-        need = n
-        if need > len(d.e_src):
-            pad = need - len(d.e_src)
-            d.e_src = np.r_[d.e_src, np.full(pad, SENTINEL, np.int32)]
-            d.e_dst = np.r_[d.e_dst, np.full(pad, SENTINEL, np.int32)]
-            d.e_op = np.r_[d.e_op, np.zeros(pad, np.int8)]
-            d.e_val = np.r_[d.e_val, np.full(pad, -1, np.int32)]
-        d.e_src[:n] = gslot[order].astype(np.int32)
-        d.e_dst[:n] = m_dst[order]
-        d.e_op[:n] = (m_kinds[order] != EDGE_DEL).astype(np.int8)
-        d.e_val[:n] = m_vals[order]
-    return d
 
 
 def overlay_fold(deltas: List[Delta], ecap: Optional[int] = None,
@@ -294,54 +298,58 @@ def overlay_fold(deltas: List[Delta], ecap: Optional[int] = None,
     """Σ over an ordered delta chain (Algorithm 1's merge).  The node
     payload uses the fused overlay (Pallas kernel on TPU; numpy/jnp ref
     here); edges use the sorted last-wins merge."""
-    assert deltas
-    if use_kernel:
-        from repro.kernels.delta_overlay import ops as ov_ops
+    with trace.span("snapshot.overlay_fold"):
+        assert deltas
+        if use_kernel:
+            from repro.kernels.delta_overlay import ops as ov_ops
 
-        node_part = ov_ops.overlay(
-            np.stack([d.valid for d in deltas]),
-            np.stack([d.present for d in deltas]),
-            np.stack([d.attrs for d in deltas]),
-        )
-        acc = deltas[0].copy()
-        acc.valid, acc.present, acc.attrs = (np.asarray(x) for x in node_part)
+            node_part = ov_ops.overlay(
+                np.stack([d.valid for d in deltas]),
+                np.stack([d.present for d in deltas]),
+                np.stack([d.attrs for d in deltas]),
+            )
+            acc = deltas[0].copy()
+            with trace.span("overlay.readback"):
+                acc.valid, acc.present, acc.attrs = (
+                    np.asarray(x) for x in node_part)
+            for d in deltas[1:]:
+                acc.e_src, acc.e_dst, acc.e_op, acc.e_val = delta_mod._edge_sum(acc, d, ecap)
+            return acc
+        acc = deltas[0]
         for d in deltas[1:]:
-            acc.e_src, acc.e_dst, acc.e_op, acc.e_val = delta_mod._edge_sum(acc, d, ecap)
+            acc = delta_mod.delta_sum(acc, d, ecap)
         return acc
-    acc = deltas[0]
-    for d in deltas[1:]:
-        acc = delta_mod.delta_sum(acc, d, ecap)
-    return acc
 
 
 def delta_to_graph(d: Delta, smap: SlotMap) -> GraphState:
     """Materialize a reconstructed snapshot Delta back to GraphState."""
-    K = d.attrs.shape[-1]
-    rev = smap.reverse()  # (P, psize) -> nid
-    n_nodes = int(smap.node_ids.max()) + 1 if len(smap.node_ids) else 0
-    g = GraphState.empty(n_nodes, K)
-    on = d.valid & (d.present == 1)
-    nids = rev[on]
-    g.present[nids] = 1
-    g.attrs[nids] = d.attrs[on]
-    ne = int((d.e_src != SENTINEL).sum())
-    if ne:
-        keep = d.e_op[:ne] == 1
-        gslot = d.e_src[:ne][keep].astype(np.int64)
-        pid = (gslot // smap.psize).astype(np.int32)
-        slot = (gslot % smap.psize).astype(np.int32)
-        src = rev[pid, slot]
-        dst = d.e_dst[:ne][keep]
-        # canonicalize mirrored copies (edges stored under both endpoints)
-        lo = np.minimum(src.astype(np.int64), dst.astype(np.int64))
-        hi = np.maximum(src.astype(np.int64), dst.astype(np.int64))
-        key = pack_edge_key(lo, hi)
-        val = d.e_val[:ne][keep]
-        order = np.argsort(key, kind="stable")
-        key, val = key[order], val[order]
-        uniq = np.ones(len(key), bool)
-        if len(key) > 1:
-            uniq[1:] = key[1:] != key[:-1]
-        g.edge_key = key[uniq]
-        g.edge_val = val[uniq]
-    return g
+    with trace.span("snapshot.delta_to_graph"):
+        K = d.attrs.shape[-1]
+        rev = smap.reverse()  # (P, psize) -> nid
+        n_nodes = int(smap.node_ids.max()) + 1 if len(smap.node_ids) else 0
+        g = GraphState.empty(n_nodes, K)
+        on = d.valid & (d.present == 1)
+        nids = rev[on]
+        g.present[nids] = 1
+        g.attrs[nids] = d.attrs[on]
+        ne = int((d.e_src != SENTINEL).sum())
+        if ne:
+            keep = d.e_op[:ne] == 1
+            gslot = d.e_src[:ne][keep].astype(np.int64)
+            pid = (gslot // smap.psize).astype(np.int32)
+            slot = (gslot % smap.psize).astype(np.int32)
+            src = rev[pid, slot]
+            dst = d.e_dst[:ne][keep]
+            # canonicalize mirrored copies (edges stored under both endpoints)
+            lo = np.minimum(src.astype(np.int64), dst.astype(np.int64))
+            hi = np.maximum(src.astype(np.int64), dst.astype(np.int64))
+            key = pack_edge_key(lo, hi)
+            val = d.e_val[:ne][keep]
+            order = np.argsort(key, kind="stable")
+            key, val = key[order], val[order]
+            uniq = np.ones(len(key), bool)
+            if len(key) > 1:
+                uniq[1:] = key[1:] != key[:-1]
+            g.edge_key = key[uniq]
+            g.edge_val = val[uniq]
+        return g

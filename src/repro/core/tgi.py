@@ -53,6 +53,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core import delta as delta_mod
 from repro.core import faultpoints
 from repro.core import ingest as ingest_mod
@@ -225,21 +226,23 @@ class TGI:
         if view is not None:
             yield view
             return
-        with self._mvcc:
+        # two spans of one name: the guard's own cost, not its body's
+        with trace.span("tgi.read_guard"), self._mvcc:
             view = self._capture_view_locked()
             self._pinned[view.epoch] = self._pinned.get(view.epoch, 0) + 1
         tls.view = view
         try:
             yield view
         finally:
-            tls.view = None
-            with self._mvcc:
-                n = self._pinned.get(view.epoch, 1) - 1
-                if n <= 0:
-                    self._pinned.pop(view.epoch, None)
-                else:
-                    self._pinned[view.epoch] = n
-            self._gc_drain()
+            with trace.span("tgi.read_guard"):
+                tls.view = None
+                with self._mvcc:
+                    n = self._pinned.get(view.epoch, 1) - 1
+                    if n <= 0:
+                        self._pinned.pop(view.epoch, None)
+                    else:
+                        self._pinned[view.epoch] = n
+                self._gc_drain()
 
     def _tls_view(self) -> Optional[ReadView]:
         return getattr(self._tls, "view", None)
@@ -653,7 +656,9 @@ class TGI:
 
         def _run():
             try:
-                fut.set_result(self._compact_pass(min_run))
+                with trace.span("tgi.maintenance"):
+                    stats = self._compact_pass(min_run)
+                fut.set_result(stats)
             except BaseException as e:  # surfaced via fut.result()
                 with self._mvcc:
                     self.maintenance_stats["failed_passes"] += 1
@@ -880,41 +885,42 @@ class TGI:
     def _fetch_delta(self, tsid: int, did: str, pids: Optional[Sequence[int]],
                      si: SpanIndex, c: int = 1,
                      projection: Optional[Sequence[str]] = None) -> Delta:
-        cfg = self.cfg
-        pids = list(range(cfg.n_parts)) if pids is None else list(pids)
-        keys = self._delta_keys(tsid, did, pids)
-        fields = None
-        if projection is not None and "attrs" not in projection:
-            # attribute-projection pushdown: the attrs tile (the widest
-            # column) is never read off storage
-            fields = tuple(f for f in DELTA_FIELDS if f != "attrs")
-        sizes: Dict[DeltaKey, ReadSizes] = {}
-        got = self.store.multiget(keys, c=c, fields=fields, sizes=sizes)
-        psize = si.smap.psize
-        d = Delta.empty(cfg.n_parts, psize, cfg.n_attrs, ecap=1)
-        e_parts = []
-        for p, k in zip(pids, keys):
-            a = got[k]
-            d.valid[p] = a["valid"]
-            d.present[p] = a["present"]
-            if "attrs" in a:
-                d.attrs[p] = a["attrs"]
-            ne = int((a["e_src"] != SENTINEL).sum())
-            e_parts.append((a["e_src"][:ne], a["e_dst"][:ne], a["e_op"][:ne], a["e_val"][:ne]))
-            s = sizes[k]
-            self._record_cost(1, s.enc, int(a["valid"].sum()) + ne, s.raw,
-                              s.pool, s.pool_cols)
-        if e_parts:
-            d.e_src = np.concatenate([e[0] for e in e_parts])
-            d.e_dst = np.concatenate([e[1] for e in e_parts])
-            d.e_op = np.concatenate([e[2] for e in e_parts])
-            d.e_val = np.concatenate([e[3] for e in e_parts])
-            if len(d.e_src) == 0:
-                d.e_src = np.full(1, SENTINEL, np.int32)
-                d.e_dst = np.full(1, SENTINEL, np.int32)
-                d.e_op = np.zeros(1, np.int8)
-                d.e_val = np.full(1, -1, np.int32)
-        return d
+        with trace.span("tgi.fetch_delta"):
+            cfg = self.cfg
+            pids = list(range(cfg.n_parts)) if pids is None else list(pids)
+            keys = self._delta_keys(tsid, did, pids)
+            fields = None
+            if projection is not None and "attrs" not in projection:
+                # attribute-projection pushdown: the attrs tile (the widest
+                # column) is never read off storage
+                fields = tuple(f for f in DELTA_FIELDS if f != "attrs")
+            sizes: Dict[DeltaKey, ReadSizes] = {}
+            got = self.store.multiget(keys, c=c, fields=fields, sizes=sizes)
+            psize = si.smap.psize
+            d = Delta.empty(cfg.n_parts, psize, cfg.n_attrs, ecap=1)
+            e_parts = []
+            for p, k in zip(pids, keys):
+                a = got[k]
+                d.valid[p] = a["valid"]
+                d.present[p] = a["present"]
+                if "attrs" in a:
+                    d.attrs[p] = a["attrs"]
+                ne = int((a["e_src"] != SENTINEL).sum())
+                e_parts.append((a["e_src"][:ne], a["e_dst"][:ne], a["e_op"][:ne], a["e_val"][:ne]))
+                s = sizes[k]
+                self._record_cost(1, s.enc, int(a["valid"].sum()) + ne, s.raw,
+                                  s.pool, s.pool_cols)
+            if e_parts:
+                d.e_src = np.concatenate([e[0] for e in e_parts])
+                d.e_dst = np.concatenate([e[1] for e in e_parts])
+                d.e_op = np.concatenate([e[2] for e in e_parts])
+                d.e_val = np.concatenate([e[3] for e in e_parts])
+                if len(d.e_src) == 0:
+                    d.e_src = np.full(1, SENTINEL, np.int32)
+                    d.e_dst = np.full(1, SENTINEL, np.int32)
+                    d.e_op = np.zeros(1, np.int8)
+                    d.e_val = np.full(1, -1, np.int32)
+            return d
 
     def _fetch_eventlists(self, si: SpanIndex, b_lo: int, b_hi: int,
                           c: int = 1,
@@ -923,37 +929,38 @@ class TGI:
         replicated to both endpoints' shards, so a fetch restricted to
         the shards covering a partition subset still sees every event
         with >=1 endpoint there (planner shard pruning)."""
-        keys = []
-        for b in range(b_lo, b_hi):
-            for sid in (range(self.cfg.n_shards) if sids is None else sids):
-                keys.append(DeltaKey(si.span.tsid, sid, f"E:{b}", 0))
-        out = EventLog.empty()
-        # a bucket may have no events on a given shard -> key absent;
-        # the stored pid column is for micro reads only — project it
-        # away so it is seeked over, never decoded
-        sizes: Dict[DeltaKey, ReadSizes] = {}
-        got = self.store.multiget(keys, c=c, missing_ok=True, sizes=sizes,
-                                  fields=("t", "kind", "src", "dst", "key", "val"))
-        logs = []
-        for k in keys:
-            if k not in got:
-                continue
-            a = got[k]
-            s = sizes[k]
-            self._record_cost(1, s.enc, len(a["t"]), s.raw, s.pool, s.pool_cols)
-            logs.append(a)
-        if not logs:
-            return out
-        cat = {c2: np.concatenate([l[c2] for l in logs]) for c2 in
-               ("t", "kind", "src", "dst", "key", "val")}
-        ev = EventLog(**cat)
-        # events were replicated across shards: dedup identical rows
-        rows = np.stack([ev.t, ev.kind.astype(np.int64), ev.src.astype(np.int64),
-                         ev.dst.astype(np.int64), ev.key.astype(np.int64),
-                         ev.val.astype(np.int64)], 1)
-        _, uniq = np.unique(rows, axis=0, return_index=True)
-        ev = ev.take(np.sort(uniq))
-        return ev.take(np.argsort(ev.t, kind="stable"))
+        with trace.span("tgi.fetch_eventlists"):
+            keys = []
+            for b in range(b_lo, b_hi):
+                for sid in (range(self.cfg.n_shards) if sids is None else sids):
+                    keys.append(DeltaKey(si.span.tsid, sid, f"E:{b}", 0))
+            out = EventLog.empty()
+            # a bucket may have no events on a given shard -> key absent;
+            # the stored pid column is for micro reads only — project it
+            # away so it is seeked over, never decoded
+            sizes: Dict[DeltaKey, ReadSizes] = {}
+            got = self.store.multiget(keys, c=c, missing_ok=True, sizes=sizes,
+                                      fields=("t", "kind", "src", "dst", "key", "val"))
+            logs = []
+            for k in keys:
+                if k not in got:
+                    continue
+                a = got[k]
+                s = sizes[k]
+                self._record_cost(1, s.enc, len(a["t"]), s.raw, s.pool, s.pool_cols)
+                logs.append(a)
+            if not logs:
+                return out
+            cat = {c2: np.concatenate([l[c2] for l in logs]) for c2 in
+                   ("t", "kind", "src", "dst", "key", "val")}
+            ev = EventLog(**cat)
+            # events were replicated across shards: dedup identical rows
+            rows = np.stack([ev.t, ev.kind.astype(np.int64), ev.src.astype(np.int64),
+                             ev.dst.astype(np.int64), ev.key.astype(np.int64),
+                             ev.val.astype(np.int64)], 1)
+            _, uniq = np.unique(rows, axis=0, return_index=True)
+            ev = ev.take(np.sort(uniq))
+            return ev.take(np.argsort(ev.t, kind="stable"))
 
     def _leaf_for(self, si: SpanIndex, t: int) -> int:
         """Nearest derived-hierarchy checkpoint at or before t."""
@@ -1098,38 +1105,39 @@ class TGI:
         re-record the logical fetch cost.  Reads at t past the sealed
         history (mid-stream ``append``) overlay the ingest buffer's live
         events and bypass the LRU."""
-        self.last_cost = FetchCost()
-        with self.read_guard() as view:
-            p0 = self._pending_floor(view)
-            open_read = p0 is not None and t >= p0
-            key = self._snap_key(t, pids, projection, c)
-            if not open_read:
-                hit = self._snap_cache_get(key, epoch=view.epoch)
-                if hit is not None:
-                    return hit
-            with self.cost_scope() as acc:
-                si = self._span_index(t, view)
-                leaf = self._leaf_for(si, t)
-                path = self._hierarchy_path(si, leaf)
-                deltas = [self._fetch_delta(si.span.tsid, did, pids, si, c,
-                                            projection)
-                          for did in path]
-                state = overlay_fold(deltas, use_kernel=use_kernel)
-                t_ck = si.checkpoint_ts[leaf]
-                ev = self._span_events_until(si, t_ck, t, c, pids, view)
-                if len(ev):
-                    state = overlay_fold(
-                        [state, events_to_delta(ev, si.smap, self.cfg.n_attrs)],
-                        use_kernel=use_kernel,
-                    )
-                if pids is not None:
-                    state = self._restrict_pids(state, si, pids)
-                g = delta_to_graph(state, si.smap)
-                if open_read:
-                    g = self._overlay_pending(g, t, si, pids, view)
-            if not open_read:
-                self._snap_cache_put(key, g, acc, epoch=view.epoch)
-            return g
+        with trace.span("tgi.get_snapshot"):
+            self.last_cost = FetchCost()
+            with self.read_guard() as view:
+                p0 = self._pending_floor(view)
+                open_read = p0 is not None and t >= p0
+                key = self._snap_key(t, pids, projection, c)
+                if not open_read:
+                    hit = self._snap_cache_get(key, epoch=view.epoch)
+                    if hit is not None:
+                        return hit
+                with self.cost_scope() as acc:
+                    si = self._span_index(t, view)
+                    leaf = self._leaf_for(si, t)
+                    path = self._hierarchy_path(si, leaf)
+                    deltas = [self._fetch_delta(si.span.tsid, did, pids, si, c,
+                                                projection)
+                              for did in path]
+                    state = overlay_fold(deltas, use_kernel=use_kernel)
+                    t_ck = si.checkpoint_ts[leaf]
+                    ev = self._span_events_until(si, t_ck, t, c, pids, view)
+                    if len(ev):
+                        state = overlay_fold(
+                            [state, events_to_delta(ev, si.smap, self.cfg.n_attrs)],
+                            use_kernel=use_kernel,
+                        )
+                    if pids is not None:
+                        state = self._restrict_pids(state, si, pids)
+                    g = delta_to_graph(state, si.smap)
+                    if open_read:
+                        g = self._overlay_pending(g, t, si, pids, view)
+                if not open_read:
+                    self._snap_cache_put(key, g, acc, epoch=view.epoch)
+                return g
 
     def get_snapshots(self, ts: Sequence[int], c: int = 1,
                       pids: Optional[Sequence[int]] = None,
@@ -1144,52 +1152,53 @@ class TGI:
 
         ``last_cost`` totals the whole batch.  Bit-identical to
         ``[get_snapshot(t) for t in ts]`` (property-tested)."""
-        ts_list = [int(t) for t in np.asarray(ts, np.int64).ravel()]
-        out: List[Optional[GraphState]] = [None] * len(ts_list)
-        self.last_cost = FetchCost()
-        with self.read_guard() as view:
-            p0 = self._pending_floor(view)
-            groups: Dict[Tuple[int, int], List[int]] = {}
-            for j, t in enumerate(ts_list):
-                if p0 is None or t < p0:  # open reads bypass the LRU
-                    hit = self._snap_cache_get(
-                        self._snap_key(t, pids, projection, c),
-                        epoch=view.epoch)
-                    if hit is not None:
-                        out[j] = hit
-                        continue
-                si = self._span_index(t, view)
-                groups.setdefault((si.span.tsid, self._leaf_for(si, t)),
-                                  []).append(j)
-            for (tsid, leaf), members in groups.items():
-                si = view.span_by_tsid[tsid]
-                t_ck = si.checkpoint_ts[leaf]
-                t_hi = max(ts_list[j] for j in members)
-                path = self._hierarchy_path(si, leaf)
-                path_deltas = [
-                    self._fetch_delta(tsid, did, pids, si, c, projection)
-                    for did in path
-                ]
-                ev = self._span_events_until(si, t_ck, t_hi, c, pids, view)
-                ev_deltas = []
-                for j in members:
-                    ev_j = ev.take(np.nonzero(ev.t <= ts_list[j])[0])
-                    ev_deltas.append(
-                        events_to_delta(ev_j, si.smap, self.cfg.n_attrs)
-                        if len(ev_j) else None
-                    )
-                states = self._fold_group(path_deltas, ev_deltas, use_kernel)
-                for j, state in zip(members, states):
-                    if pids is not None:
-                        state = self._restrict_pids(state, si, pids)
-                    g = delta_to_graph(state, si.smap)
-                    if p0 is not None and ts_list[j] >= p0:
-                        g = self._overlay_pending(g, ts_list[j], si, pids, view)
-                    out[j] = g
-                # NOT inserted into the snapshot LRU: the group's fetch cost
-                # is shared across members, so a per-t entry would over-
-                # report the logical cost on later single-t cache hits
-        return out  # type: ignore[return-value]
+        with trace.span("tgi.get_snapshots"):
+            ts_list = [int(t) for t in np.asarray(ts, np.int64).ravel()]
+            out: List[Optional[GraphState]] = [None] * len(ts_list)
+            self.last_cost = FetchCost()
+            with self.read_guard() as view:
+                p0 = self._pending_floor(view)
+                groups: Dict[Tuple[int, int], List[int]] = {}
+                for j, t in enumerate(ts_list):
+                    if p0 is None or t < p0:  # open reads bypass the LRU
+                        hit = self._snap_cache_get(
+                            self._snap_key(t, pids, projection, c),
+                            epoch=view.epoch)
+                        if hit is not None:
+                            out[j] = hit
+                            continue
+                    si = self._span_index(t, view)
+                    groups.setdefault((si.span.tsid, self._leaf_for(si, t)),
+                                      []).append(j)
+                for (tsid, leaf), members in groups.items():
+                    si = view.span_by_tsid[tsid]
+                    t_ck = si.checkpoint_ts[leaf]
+                    t_hi = max(ts_list[j] for j in members)
+                    path = self._hierarchy_path(si, leaf)
+                    path_deltas = [
+                        self._fetch_delta(tsid, did, pids, si, c, projection)
+                        for did in path
+                    ]
+                    ev = self._span_events_until(si, t_ck, t_hi, c, pids, view)
+                    ev_deltas = []
+                    for j in members:
+                        ev_j = ev.take(np.nonzero(ev.t <= ts_list[j])[0])
+                        ev_deltas.append(
+                            events_to_delta(ev_j, si.smap, self.cfg.n_attrs)
+                            if len(ev_j) else None
+                        )
+                    states = self._fold_group(path_deltas, ev_deltas, use_kernel)
+                    for j, state in zip(members, states):
+                        if pids is not None:
+                            state = self._restrict_pids(state, si, pids)
+                        g = delta_to_graph(state, si.smap)
+                        if p0 is not None and ts_list[j] >= p0:
+                            g = self._overlay_pending(g, ts_list[j], si, pids, view)
+                        out[j] = g
+                    # NOT inserted into the snapshot LRU: the group's fetch cost
+                    # is shared across members, so a per-t entry would over-
+                    # report the logical cost on later single-t cache hits
+            return out  # type: ignore[return-value]
 
     def _fold_group(self, path_deltas: List[Delta],
                     ev_deltas: List[Optional[Delta]],
@@ -1217,7 +1226,8 @@ class TGI:
                 tmask,
             )
             # (P, S, T[, K]): timepoint j is axis 2
-            v, p, a = np.asarray(v), np.asarray(p), np.asarray(a)
+            with trace.span("overlay.readback"):
+                v, p, a = np.asarray(v), np.asarray(p), np.asarray(a)
             states = []
             for j, d in enumerate(ev_deltas):
                 st = base.copy()
@@ -1238,45 +1248,46 @@ class TGI:
         """Algorithm 2: (initial state at t0, EventLog of changes (t0,t1]).
         Buffered (unsealed) events in the window ride along from memory —
         they are not yet referenced by the version chains."""
-        self.last_cost = FetchCost()
-        with self.read_guard() as view:
-            si = self._span_index(t0, view)
-            pid, slot, found = si.smap.lookup(np.asarray([nid]))
-            p0 = self._pending_floor(view)
-            pend_has_nid = False
-            if p0 is not None and t0 >= p0:
-                pend0 = view.pending.up_to(t0)
-                pend_has_nid = bool(
-                    ((pend0.src == nid) | (pend0.dst == nid)).any())
-            init = None
-            if found[0] or pend_has_nid:
-                # a node only the buffer knows has no sealed partition
-                # yet — fall back to the unrestricted overlay read
-                snap = self.get_snapshot(
-                    t0, c=c, pids=[int(pid[0])] if found[0] else None)
-                if nid < len(snap.present) and snap.present[nid]:
-                    init = {
-                        "present": 1,
-                        "attrs": snap.attrs[nid].copy(),
-                        "neighbors": self._neighbors_of(snap, nid),
-                    }
-            ts, tsids, buckets = view.vc.get(nid, t0, t1)
-            ev = EventLog.empty()
-            for tsid in np.unique(tsids):
-                si2 = view.span_by_tsid[int(tsid)]
-                bks = np.unique(buckets[tsids == tsid])
-                # events touching nid replicate to nid's shard: read it alone
-                pid2, _, found2 = si2.smap.lookup(np.asarray([nid]))
-                sids = [self._sid_of_pid(int(pid2[0]))] if found2[0] else None
-                got = self._fetch_eventlists(si2, int(bks.min()),
-                                             int(bks.max()) + 1, c, sids=sids)
-                ev = ev.concat(got, sort=False)
-            if p0 is not None and t1 >= p0:
-                ev = ev.concat(view.pending.slice_time(t0, t1), sort=False)
-            ev = ev.take(np.argsort(ev.t, kind="stable"))
-            sel = (((ev.src == nid) | (ev.dst == nid))
-                   & (ev.t > t0) & (ev.t <= t1))
-            return init, ev.take(np.nonzero(sel)[0])
+        with trace.span("tgi.get_node_history"):
+            self.last_cost = FetchCost()
+            with self.read_guard() as view:
+                si = self._span_index(t0, view)
+                pid, slot, found = si.smap.lookup(np.asarray([nid]))
+                p0 = self._pending_floor(view)
+                pend_has_nid = False
+                if p0 is not None and t0 >= p0:
+                    pend0 = view.pending.up_to(t0)
+                    pend_has_nid = bool(
+                        ((pend0.src == nid) | (pend0.dst == nid)).any())
+                init = None
+                if found[0] or pend_has_nid:
+                    # a node only the buffer knows has no sealed partition
+                    # yet — fall back to the unrestricted overlay read
+                    snap = self.get_snapshot(
+                        t0, c=c, pids=[int(pid[0])] if found[0] else None)
+                    if nid < len(snap.present) and snap.present[nid]:
+                        init = {
+                            "present": 1,
+                            "attrs": snap.attrs[nid].copy(),
+                            "neighbors": self._neighbors_of(snap, nid),
+                        }
+                ts, tsids, buckets = view.vc.get(nid, t0, t1)
+                ev = EventLog.empty()
+                for tsid in np.unique(tsids):
+                    si2 = view.span_by_tsid[int(tsid)]
+                    bks = np.unique(buckets[tsids == tsid])
+                    # events touching nid replicate to nid's shard: read it alone
+                    pid2, _, found2 = si2.smap.lookup(np.asarray([nid]))
+                    sids = [self._sid_of_pid(int(pid2[0]))] if found2[0] else None
+                    got = self._fetch_eventlists(si2, int(bks.min()),
+                                                 int(bks.max()) + 1, c, sids=sids)
+                    ev = ev.concat(got, sort=False)
+                if p0 is not None and t1 >= p0:
+                    ev = ev.concat(view.pending.slice_time(t0, t1), sort=False)
+                ev = ev.take(np.argsort(ev.t, kind="stable"))
+                sel = (((ev.src == nid) | (ev.dst == nid))
+                       & (ev.t > t0) & (ev.t <= t1))
+                return init, ev.take(np.nonzero(sel)[0])
 
     def _neighbors_of(self, g: GraphState, nid: int) -> np.ndarray:
         src, dst, _ = g.edges()
@@ -1290,7 +1301,7 @@ class TGI:
         sizes discounted by decoded-block-pool residency (see
         ``explain_k_hop``) — instead of the paper's fixed k<=2 rule
         (which remains the tie-break)."""
-        with self.read_guard() as view:
+        with trace.span("tgi.get_k_hop"), self.read_guard() as view:
             if method == "auto":
                 method = self.explain_k_hop(nid, t, k)["method"]
             if method == "snapshot":

@@ -8,6 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.device import interpret
 from repro.kernels.delta_overlay import ref
 from repro.kernels.delta_overlay.delta_overlay import (
@@ -71,12 +72,13 @@ def _overlay_batch(valid, present, attrs, tmask, interpret):
 def overlay(valid, present, attrs, use_pallas: bool = True):
     """Fold stacked deltas (h, P, S[, K]) -> (P, S[, K]); valid comes
     back bool.  Accepts numpy or jnp."""
-    valid = jnp.asarray(valid)
-    present = jnp.asarray(present)
-    attrs = jnp.asarray(attrs)
-    if not use_pallas:
-        return ref.overlay_ref(valid, present, attrs)
-    return _overlay(valid, present, attrs, interpret=interpret())
+    with trace.span("overlay.dispatch"):
+        valid = jnp.asarray(valid)
+        present = jnp.asarray(present)
+        attrs = jnp.asarray(attrs)
+        if not use_pallas:
+            return ref.overlay_ref(valid, present, attrs)
+        return _overlay(valid, present, attrs, interpret=interpret())
 
 
 def overlay_batch(valid, present, attrs, tmask, use_pallas: bool = True):
@@ -89,12 +91,14 @@ def overlay_batch(valid, present, attrs, tmask, use_pallas: bool = True):
     eventlist layer).  Accepts numpy or jnp; runs the Pallas kernel, or
     the pure-jnp reference with ``use_pallas=False``.
     """
-    valid = jnp.asarray(valid)
-    present = jnp.asarray(present)
-    attrs = jnp.asarray(attrs)
-    tmask = jnp.asarray(tmask, jnp.int32)
-    if not use_pallas:
-        out_v, out_p, out_a = ref.overlay_batch_ref(
-            valid.astype(jnp.int8), present, attrs, tmask)
-        return out_v != 0, out_p, out_a
-    return _overlay_batch(valid, present, attrs, tmask, interpret=interpret())
+    with trace.span("overlay.dispatch"):
+        valid = jnp.asarray(valid)
+        present = jnp.asarray(present)
+        attrs = jnp.asarray(attrs)
+        tmask = jnp.asarray(tmask, jnp.int32)
+        if not use_pallas:
+            out_v, out_p, out_a = ref.overlay_batch_ref(
+                valid.astype(jnp.int8), present, attrs, tmask)
+            return out_v != 0, out_p, out_a
+        return _overlay_batch(valid, present, attrs, tmask,
+                              interpret=interpret())

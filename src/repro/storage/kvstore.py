@@ -44,6 +44,7 @@ from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core import faultpoints
 from repro.storage import serialize
 from repro.storage.serialize import BlockCorruption  # re-export  # noqa: F401
@@ -859,7 +860,9 @@ class DeltaStore:
         if self.backend == "file" and self.seek:
             return self._read_columns_seek(node, key, fields)
         blob = self._read_node(node, key)
-        arrays, enc_read, raw_read = serialize.loads_sized(blob, fields=fields)
+        with trace.span("serialize.decode"):
+            arrays, enc_read, raw_read = serialize.loads_sized(
+                blob, fields=fields)
         self._pool_dir_fill(key, blob)
         return arrays, enc_read, raw_read
 
@@ -911,8 +914,9 @@ class DeltaStore:
             blob = prefix + self._pread_exact(
                 fd, blen - len(prefix), off + len(prefix))
             io_bytes += max(blen - len(prefix), 0)
-            arrays, enc_read, raw_read = serialize.loads_sized(
-                blob, fields=fields)
+            with trace.span("serialize.decode"):
+                arrays, enc_read, raw_read = serialize.loads_sized(
+                    blob, fields=fields)
             self._pool_dir_fill(key, blob)
             with self._lock:
                 self.stats.bytes_io += io_bytes
@@ -935,17 +939,18 @@ class DeltaStore:
         arrays: Dict[str, np.ndarray] = {}
         enc_read, raw_read = 8, 0
         view = memoryview(prefix)
-        for e in entries:
-            if want is not None and e.name not in want:
-                continue
-            if e.off + e.length <= len(prefix):
-                payload = view[e.off : e.off + e.length]
-            else:
-                payload = self._pread_exact(fd, e.length, off + e.off)
-                io_bytes += e.length
-            arrays[e.name] = serialize.decode_entry(e, payload)
-            enc_read += e.length
-            raw_read += arrays[e.name].nbytes
+        with trace.span("serialize.decode"):
+            for e in entries:
+                if want is not None and e.name not in want:
+                    continue
+                if e.off + e.length <= len(prefix):
+                    payload = view[e.off : e.off + e.length]
+                else:
+                    payload = self._pread_exact(fd, e.length, off + e.off)
+                    io_bytes += e.length
+                arrays[e.name] = serialize.decode_entry(e, payload)
+                enc_read += e.length
+                raw_read += arrays[e.name].nbytes
         with self._lock:
             self.stats.bytes_io += io_bytes
         return arrays, enc_read, raw_read
@@ -1090,25 +1095,26 @@ class DeltaStore:
         key (``StoreStats.hedged_reads`` counts them).  With
         ``missing_ok`` absent keys are skipped instead of raising (sparse
         key spaces like per-shard eventlists); node failures still raise."""
-        keys = list(keys)
-        groups: Dict[int, List[DeltaKey]] = {}
-        for k in keys:
-            groups.setdefault(self.replicas(k)[0], []).append(k)
-        out: Dict[DeltaKey, Dict] = {}
-        if c <= 1 or len(groups) == 1:
-            for primary, gkeys in groups.items():
-                out.update(self._group_fetch(primary, gkeys, fields,
-                                             missing_ok, sizes))
+        with trace.span("kvstore.multiget"):
+            keys = list(keys)
+            groups: Dict[int, List[DeltaKey]] = {}
+            for k in keys:
+                groups.setdefault(self.replicas(k)[0], []).append(k)
+            out: Dict[DeltaKey, Dict] = {}
+            if c <= 1 or len(groups) == 1:
+                for primary, gkeys in groups.items():
+                    out.update(self._group_fetch(primary, gkeys, fields,
+                                                 missing_ok, sizes))
+                return out
+            with cf.ThreadPoolExecutor(max_workers=c) as ex:
+                futs = [
+                    ex.submit(self._group_fetch, primary, gkeys, fields,
+                              missing_ok, sizes)
+                    for primary, gkeys in groups.items()
+                ]
+                for fut in cf.as_completed(futs):
+                    out.update(fut.result())
             return out
-        with cf.ThreadPoolExecutor(max_workers=c) as ex:
-            futs = [
-                ex.submit(self._group_fetch, primary, gkeys, fields,
-                          missing_ok, sizes)
-                for primary, gkeys in groups.items()
-            ]
-            for fut in cf.as_completed(futs):
-                out.update(fut.result())
-        return out
 
     def _group_fetch(self, primary: int, gkeys: List[DeltaKey],
                      fields: Optional[Iterable[str]], missing_ok: bool,

@@ -46,6 +46,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core.events import NATTR_SET, NODE_ADD, NODE_DEL
 from repro.taf import operators as ops
 from repro.taf import replay
@@ -675,16 +676,18 @@ def _fused_slice(operand, stage, replay_cache):
             value = {kk: (vv.copy() if isinstance(vv, np.ndarray) else vv)
                      for kk, vv in hit.items()}
             return value, ("compile: fused slice (replay-LRU hit)",)
-    node = _node_arrays(operand)
-    key = ("slice", _shape_sig(node), T)
-    hit_before = key in _programs
-    prog = _get_program(key, _build_slice_program)
-    pres, attrs = prog(node, _tsv(ts))
-    value = {
-        "present": np.asarray(pres).astype(operand.init_present.dtype),
-        "attrs": np.asarray(attrs).astype(operand.init_attrs.dtype),
-        "t": ts,
-    }
+    with trace.span("compile.dispatch"):
+        node = _node_arrays(operand)
+        key = ("slice", _shape_sig(node), T)
+        hit_before = key in _programs
+        prog = _get_program(key, _build_slice_program)
+        pres, attrs = prog(node, _tsv(ts))
+    with trace.span("compile.readback"):
+        value = {
+            "present": np.asarray(pres).astype(operand.init_present.dtype),
+            "attrs": np.asarray(attrs).astype(operand.init_attrs.dtype),
+            "t": ts,
+        }
     if replay_cache is not None:
         replay_cache.put(ckey, value, owner=operand)
         value = {kk: (vv.copy() if isinstance(vv, np.ndarray) else vv)
@@ -710,14 +713,16 @@ def _fused_compute(operand, stage):
     miss = _budget_miss(op, operand, T)
     if miss is not None:
         return miss
-    node = _node_arrays(operand)
-    edge = _edge_arrays(operand)
-    key = ("compute", op.name, op.params(), _shape_sig(node),
-           _shape_sig(edge), T)
-    hit_before = key in _programs
-    prog = _get_program(key, lambda: _build_series_program(op))
-    series = prog(node, edge, _tsv(ts))
-    out = np.asarray(series, np.float64).reshape(len(operand), T)
+    with trace.span("compile.dispatch"):
+        node = _node_arrays(operand)
+        edge = _edge_arrays(operand)
+        key = ("compute", op.name, op.params(), _shape_sig(node),
+               _shape_sig(edge), T)
+        hit_before = key in _programs
+        prog = _get_program(key, lambda: _build_series_program(op))
+        series = prog(node, edge, _tsv(ts))
+    with trace.span("compile.readback"):
+        out = np.asarray(series, np.float64).reshape(len(operand), T)
     STATS["fused_runs"] += 1
     note = (f"compile: fused compute[{op.name}] (T={T}, "
             f"{'cache hit' if hit_before else 'traced'})")
@@ -736,14 +741,17 @@ def _fused_evolution(operand, stage):
     miss = _budget_miss(sop.base, operand, T)
     if miss is not None:
         return miss
-    node = _node_arrays(operand)
-    edge = _edge_arrays(operand)
-    key = ("evolution", sop.name, sop.params(), _shape_sig(node),
-           _shape_sig(edge), T)
-    hit_before = key in _programs
-    prog = _get_program(key, lambda: _build_evolution_program(sop))
-    reduced = prog(node, edge, _tsv(ts))
-    series = sop.epilogue(np.asarray(reduced))
+    with trace.span("compile.dispatch"):
+        node = _node_arrays(operand)
+        edge = _edge_arrays(operand)
+        key = ("evolution", sop.name, sop.params(), _shape_sig(node),
+               _shape_sig(edge), T)
+        hit_before = key in _programs
+        prog = _get_program(key, lambda: _build_evolution_program(sop))
+        reduced = prog(node, edge, _tsv(ts))
+    with trace.span("compile.readback"):
+        reduced = np.asarray(reduced)
+    series = sop.epilogue(reduced)
     STATS["fused_runs"] += 1
     note = (f"compile: fused evolution[{sop.name}] (T={T}, "
             f"{'cache hit' if hit_before else 'traced'})")

@@ -48,6 +48,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core.tgi import FetchCost
 from repro.taf import operators as ops
 from repro.taf import replay
@@ -262,31 +263,32 @@ class PlanExecutor:
             cls._fetch_cache.clear()
 
     def run(self, plan: Plan) -> PlanResult:
-        plan.validate()
-        operand: Optional[SoN] = None
-        value: Any = None
-        cost = FetchCost()
-        notes: Tuple[str, ...] = ()
-        for stage in plan.stages:
-            k = stage.kind
-            if k == "fetch":
-                operand, cost, notes = self._fetch(stage)
-                value = operand
-            elif k == "materialize":
-                operand = stage.operand
-                value = operand
-            elif k == "select":
-                operand = ops.selection(operand, stage.pred)
-                value = operand
-            elif k in TERMINAL_KINDS:
-                value, tnotes = self._terminal(operand, stage)
-                notes = notes + tnotes
-            elif k == "aggregate":
-                value = self._aggregate(value, stage.op)
-            else:  # pragma: no cover
-                raise ValueError(f"unknown stage kind {k!r}")
-        return PlanResult(value=value, cost=cost, operand=operand, plan=plan,
-                          notes=notes)
+        with trace.span("plan.run"):
+            plan.validate()
+            operand: Optional[SoN] = None
+            value: Any = None
+            cost = FetchCost()
+            notes: Tuple[str, ...] = ()
+            for stage in plan.stages:
+                k = stage.kind
+                if k == "fetch":
+                    operand, cost, notes = self._fetch(stage)
+                    value = operand
+                elif k == "materialize":
+                    operand = stage.operand
+                    value = operand
+                elif k == "select":
+                    operand = ops.selection(operand, stage.pred)
+                    value = operand
+                elif k in TERMINAL_KINDS:
+                    value, tnotes = self._terminal(operand, stage)
+                    notes = notes + tnotes
+                elif k == "aggregate":
+                    value = self._aggregate(value, stage.op)
+                else:  # pragma: no cover
+                    raise ValueError(f"unknown stage kind {k!r}")
+            return PlanResult(value=value, cost=cost, operand=operand, plan=plan,
+                              notes=notes)
 
     # ---- stage implementations ----
 
@@ -333,7 +335,7 @@ class PlanExecutor:
         # same pinned epoch, and the cache key carries that epoch — a
         # concurrent maintenance publish can neither tear the operand
         # nor serve it to a reader of a different epoch
-        with self.tgi.read_guard() as _view:
+        with trace.span("plan.fetch"), self.tgi.read_guard() as _view:
             return self._fetch_guarded(stage, _view)
 
     def _fetch_guarded(self, stage: Fetch, view,

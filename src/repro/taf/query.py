@@ -22,6 +22,7 @@ from typing import Any, Callable, Iterable, Optional, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core.events import EventLog
 from repro.core.tgi import TGI, TGIConfig, FetchCost
 from repro.storage.kvstore import DeltaStore
@@ -400,11 +401,12 @@ class TemporalQuery:
 
     def run(self) -> PlanResult:
         """Compile + execute; returns PlanResult (value, cost, operand)."""
-        tgi = self.store.tgi if self.store is not None else None
-        result = PlanExecutor(tgi).run(self.plan())
-        if self.store is not None:
-            self.store.last_cost = result.cost
-        return result
+        with trace.span("query.run"):
+            tgi = self.store.tgi if self.store is not None else None
+            result = PlanExecutor(tgi).run(self.plan())
+            if self.store is not None:
+                self.store.last_cost = result.cost
+            return result
 
     def execute(self) -> Any:
         """Compile + execute; returns the result value."""

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core.events import EDGE_ADD, EDGE_DEL, NATTR_SET, NODE_ADD, NODE_DEL
 from repro.core.snapshot import GraphState, pack_edge_key
 from repro.taf.son import SoN, SoTS
@@ -91,75 +92,76 @@ def state_at_many(son: SoN, ts) -> Tuple[np.ndarray, np.ndarray]:
     (node, bucket) [presence] / (node, key, bucket) [attrs] + a forward
     fill along the sorted time axis replaces the per-timepoint rescan.
     """
-    STATS["state_at_many"] += 1
-    N = len(son)
-    K = son.init_attrs.shape[1]
-    ts, tss, order = _sorted_axis(ts)
-    T = len(ts)
-    if T == 0:
-        return (np.empty((N, 0), son.init_present.dtype),
-                np.empty((N, 0, K), son.init_attrs.dtype))
-    if not len(son.ev_t):
-        return (np.repeat(son.init_present[:, None], T, axis=1),
-                np.repeat(son.init_attrs[:, None, :], T, axis=1))
+    with trace.span("replay.state_at_many"):
+        STATS["state_at_many"] += 1
+        N = len(son)
+        K = son.init_attrs.shape[1]
+        ts, tss, order = _sorted_axis(ts)
+        T = len(ts)
+        if T == 0:
+            return (np.empty((N, 0), son.init_present.dtype),
+                    np.empty((N, 0, K), son.init_attrs.dtype))
+        if not len(son.ev_t):
+            return (np.repeat(son.init_present[:, None], T, axis=1),
+                    np.repeat(son.init_attrs[:, None, :], T, axis=1))
 
-    # bucket = first sorted timepoint the event applies to (ev_t <= t)
-    bkt_all = np.searchsorted(tss, son.ev_t, side="left")
-    idx = np.nonzero(bkt_all < T)[0]  # events beyond every timepoint drop out
-    nodes = son.node_of_events()[idx]
-    kind = son.ev_kind[idx]
-    bkt = bkt_all[idx]
+        # bucket = first sorted timepoint the event applies to (ev_t <= t)
+        bkt_all = np.searchsorted(tss, son.ev_t, side="left")
+        idx = np.nonzero(bkt_all < T)[0]  # events beyond every timepoint drop out
+        nodes = son.node_of_events()[idx]
+        kind = son.ev_kind[idx]
+        bkt = bkt_all[idx]
 
-    # --- presence: last node-state event per (node, bucket) wins ---
-    pm = (kind == NODE_ADD) | (kind == NODE_DEL) | (kind == NATTR_SET)
-    if pm.any():
-        pn, pb = nodes[pm], bkt[pm]
-        pv = (kind[pm] != NODE_DEL).astype(np.int8)
-        # CSR order is chronological within a node, and buckets are
-        # monotone in time, so group-last is a boundary test
-        last = np.r_[(pn[1:] != pn[:-1]) | (pb[1:] != pb[:-1]), True]
-        upd = np.full((N, T), -1, np.int8)
-        upd[pn[last], pb[last]] = pv[last]
-        present_s = _ffill_last_write(
-            upd >= 0, upd, son.init_present.astype(np.int8)
-        ).astype(son.init_present.dtype)
-    else:
-        present_s = np.repeat(son.init_present[:, None], T, axis=1)
+        # --- presence: last node-state event per (node, bucket) wins ---
+        pm = (kind == NODE_ADD) | (kind == NODE_DEL) | (kind == NATTR_SET)
+        if pm.any():
+            pn, pb = nodes[pm], bkt[pm]
+            pv = (kind[pm] != NODE_DEL).astype(np.int8)
+            # CSR order is chronological within a node, and buckets are
+            # monotone in time, so group-last is a boundary test
+            last = np.r_[(pn[1:] != pn[:-1]) | (pb[1:] != pb[:-1]), True]
+            upd = np.full((N, T), -1, np.int8)
+            upd[pn[last], pb[last]] = pv[last]
+            present_s = _ffill_last_write(
+                upd >= 0, upd, son.init_present.astype(np.int8)
+            ).astype(son.init_present.dtype)
+        else:
+            present_s = np.repeat(son.init_present[:, None], T, axis=1)
 
-    # --- attrs: last write per (node, key, bucket) wins; a NODE_DEL is
-    # a write of -1 to every key ---
-    am = kind == NATTR_SET
-    dm = kind == NODE_DEL
-    if am.any() or dm.any():
-        seq = idx  # chronological rank within each node's run
-        an, ak = nodes[am], son.ev_key[idx][am].astype(np.int64)
-        ab, av, aseq = bkt[am], son.ev_val[idx][am], seq[am]
-        dn, db, dseq = nodes[dm], bkt[dm], seq[dm]
-        karr = np.arange(K, dtype=np.int64)
-        wn = np.concatenate([an, np.repeat(dn, K)])
-        wk = np.concatenate([ak, np.tile(karr, len(dn))])
-        wb = np.concatenate([ab, np.repeat(db, K)])
-        wv = np.concatenate([av, np.full(len(dn) * K, -1, son.init_attrs.dtype)])
-        ws = np.concatenate([aseq, np.repeat(dseq, K)])
-        o2 = np.lexsort((ws, wb, wk, wn))
-        wn, wk, wb, wv = wn[o2], wk[o2], wb[o2], wv[o2]
-        last = np.r_[(wn[1:] != wn[:-1]) | (wk[1:] != wk[:-1])
-                     | (wb[1:] != wb[:-1]), True]
-        vals = np.zeros((N, K, T), son.init_attrs.dtype)
-        written = np.zeros((N, K, T), bool)
-        vals[wn[last], wk[last], wb[last]] = wv[last]
-        written[wn[last], wk[last], wb[last]] = True
-        attrs_s = _ffill_last_write(written, vals, son.init_attrs)
-        attrs_s = np.ascontiguousarray(attrs_s.transpose(0, 2, 1))  # (N, T, K)
-    else:
-        attrs_s = np.repeat(son.init_attrs[:, None, :], T, axis=1)
+        # --- attrs: last write per (node, key, bucket) wins; a NODE_DEL is
+        # a write of -1 to every key ---
+        am = kind == NATTR_SET
+        dm = kind == NODE_DEL
+        if am.any() or dm.any():
+            seq = idx  # chronological rank within each node's run
+            an, ak = nodes[am], son.ev_key[idx][am].astype(np.int64)
+            ab, av, aseq = bkt[am], son.ev_val[idx][am], seq[am]
+            dn, db, dseq = nodes[dm], bkt[dm], seq[dm]
+            karr = np.arange(K, dtype=np.int64)
+            wn = np.concatenate([an, np.repeat(dn, K)])
+            wk = np.concatenate([ak, np.tile(karr, len(dn))])
+            wb = np.concatenate([ab, np.repeat(db, K)])
+            wv = np.concatenate([av, np.full(len(dn) * K, -1, son.init_attrs.dtype)])
+            ws = np.concatenate([aseq, np.repeat(dseq, K)])
+            o2 = np.lexsort((ws, wb, wk, wn))
+            wn, wk, wb, wv = wn[o2], wk[o2], wb[o2], wv[o2]
+            last = np.r_[(wn[1:] != wn[:-1]) | (wk[1:] != wk[:-1])
+                         | (wb[1:] != wb[:-1]), True]
+            vals = np.zeros((N, K, T), son.init_attrs.dtype)
+            written = np.zeros((N, K, T), bool)
+            vals[wn[last], wk[last], wb[last]] = wv[last]
+            written[wn[last], wk[last], wb[last]] = True
+            attrs_s = _ffill_last_write(written, vals, son.init_attrs)
+            attrs_s = np.ascontiguousarray(attrs_s.transpose(0, 2, 1))  # (N, T, K)
+        else:
+            attrs_s = np.repeat(son.init_attrs[:, None, :], T, axis=1)
 
-    # scatter back to the caller's timepoint order
-    present = np.empty_like(present_s)
-    attrs = np.empty_like(attrs_s)
-    present[:, order] = present_s
-    attrs[:, order] = attrs_s
-    return present, attrs
+        # scatter back to the caller's timepoint order
+        present = np.empty_like(present_s)
+        attrs = np.empty_like(attrs_s)
+        present[:, order] = present_s
+        attrs[:, order] = attrs_s
+        return present, attrs
 
 
 # ---------------------------------------------------------------------------
