@@ -13,7 +13,10 @@ over the batched-replay arrays:
 * ``Compute(style="temporal", fn=<FusedOp>)`` — the temporal-analytics
   kernel family (``pagerank``/``components``/``triangles``) over
   ``EdgeReplay``'s pair table, exported once per operand via
-  ``EdgeReplay.device_export()`` and kept device-resident;
+  ``EdgeReplay.device_export()`` as a table of each pair's existence
+  changes and kept device-resident: existence at t is the pair's base
+  state XOR the parity of its changes at or before t (a compare-and-sum
+  per row, no search);
 * ``Evolution(fn=<FusedScalarOp>)``  — the same per-node programs with a
   per-timepoint reduction folded into the jit.
 
@@ -73,6 +76,8 @@ STATS: Dict[str, int] = {
     "fused_runs": 0,
     "fallback_runs": 0,
     "operand_uploads": 0,  # device-resident operand exports built
+    "flip_events": 0,      # pair events seen by the edge-operand exports
+    "flip_changes": 0,     # of them, existence changes the exports keep
 }
 
 _PROGRAM_CACHE_MAX = 64
@@ -413,17 +418,23 @@ def _edge_arrays(sots: SoTS):
     hit = _operands.get(key, owner=sots)
     if hit is not None:
         return hit
+    import jax
     import jax.numpy as jnp
 
     STATS["operand_uploads"] += 1
     N = len(sots)
     er = replay.edge_replay(sots)
     exp = er.device_export()
-    flip_t, flip_s, base = exp["flip_t"], exp["flip_s"], exp["base"]
+    STATS["flip_events"] += exp["n_events"]
+    STATS["flip_changes"] += exp["n_changes"]
+    chg_t, base = exp["chg_t"], exp["base"]
     if er.n_pairs == 0:  # dummy never-existing pair keeps gathers in-bounds
-        flip_t = np.zeros((1, 1), np.int64)
-        flip_s = np.full((1, 1), -1, np.int8)
+        chg_t = np.full((1, 1), np.iinfo(np.int64).max, np.int64)
         base = np.zeros(1, np.int8)
+    # re-sentinel the int64-max pads in the device's integer dtype (they
+    # would wrap under jax's default int32, as in ``degree_series_kernel``)
+    big = np.iinfo(jax.dtypes.canonicalize_dtype(np.int64)).max
+    chg_t = np.where(chg_t == np.iinfo(np.int64).max, big, chg_t)
     v = replay.member_rows(exp["pair_other"], sots.node_ids).astype(np.int64)
     u = exp["pair_center"].astype(np.int64)
     valid = (v >= 0) & (u != v)
@@ -457,8 +468,7 @@ def _edge_arrays(sots: SoTS):
     feid = np.concatenate([np.arange(E), np.arange(E)]).astype(np.int32)
     o = np.argsort(frow, kind="stable")
     arrs = {
-        "flip_t": jnp.asarray(flip_t),
-        "flip_s": jnp.asarray(flip_s.astype(np.int32)),
+        "chg_t": jnp.asarray(chg_t),
         "base": jnp.asarray(base.astype(np.int32)),
         "edge_u": jnp.asarray(eu),
         "edge_v": jnp.asarray(ev_),
@@ -531,21 +541,18 @@ def _dev_attrs(jnp, node, tsv, cnt_cache=None):
 
 
 def _dev_edge_live(jnp, edge, act, tsv):
-    """(E, T) f32 edge liveness from the padded flip table: pair state at
-    each timepoint (searchsorted per row), the <=2 directed pair rows
-    OR-folded by contiguous-row gather, masked by both endpoints'
-    presence.  ``act`` is (N, T) f32 — everything stays (entity, T)-major
-    so propagation scatters move whole contiguous T-rows."""
-    import jax
-
-    flip_t, flip_s = edge["flip_t"], edge["flip_s"]
-    big = jnp.iinfo(flip_t.dtype).max
-    flip_t_s = jnp.where(flip_s < 0, big, flip_t)
-    cnt = jax.vmap(lambda row: jnp.searchsorted(row, tsv, side="right"))(
-        flip_t_s)  # (P, T)
-    st_at = jnp.take_along_axis(flip_s, jnp.maximum(cnt - 1, 0), axis=1)
-    exist = jnp.where(cnt > 0, st_at, edge["base"][:, None])  # (P, T)
-    pair_live = (exist == 1).astype(jnp.float32)
+    """(E, T) f32 edge liveness from the padded table of existence
+    changes: a pair's changes alternate its state, so it exists at t iff
+    ``base XOR (number of changes <= t) mod 2`` — one compare-and-sum
+    over the C change columns of each row, no search and no gather.  The
+    <=2 directed pair rows are OR-folded by contiguous-row gather and
+    masked by both endpoints' presence.  ``act`` is (N, T) f32 —
+    everything stays (entity, T)-major so propagation scatters move
+    whole contiguous T-rows."""
+    chg_t = edge["chg_t"]  # (P, C), pads at the dtype's max
+    cnt = jnp.sum((chg_t[:, :, None] <= tsv[None, None, :]).astype(jnp.int32),
+                  axis=1)  # (P, T)
+    pair_live = (edge["base"][:, None] ^ (cnt & 1)).astype(jnp.float32)
     el = jnp.maximum(pair_live[edge["pair_a"]], pair_live[edge["pair_b"]])
     el = el * edge["edge_valid"][:, None]
     return el * act[edge["edge_u"]] * act[edge["edge_v"]]

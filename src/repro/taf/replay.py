@@ -277,36 +277,50 @@ class EdgeReplay:
         return indptr.astype(np.int64), nbrs
 
     def device_export(self) -> Dict[str, np.ndarray]:
-        """Device-friendly padded flip table (cached per EdgeReplay).
+        """Device-friendly padded table of existence changes (cached per
+        EdgeReplay).
 
-        The variable-length per-pair event runs become dense
-        ``flip_t (n_pairs, F)`` / ``flip_s (n_pairs, F)`` arrays (F = max
-        flips per pair, pad ``flip_s = -1``, pad ``flip_t = int64 max``),
-        chronological within each row.  Pair existence at any timepoint is
-        then one searchsorted per row — the layout the whole-plan compiler
+        Of each pair's chronological events only those that change its
+        state are kept: the first that differs from ``base``, then each
+        that differs from the one before.  Events that re-add a live edge
+        or delete a dead one cannot change existence at any timepoint, so
+        they go.  The kept runs become a dense ``chg_t (n_pairs, C)``
+        array (C = max changes per pair, at least 1; pad = int64 max),
+        chronological within each row.  Changes alternate, so pair
+        existence at t is ``base XOR (number of changes <= t) mod 2`` —
+        a compare-and-sum per row, the layout the whole-plan compiler
         (repro.taf.compile) uploads once per operand and reuses for every
-        jitted dispatch.  ``base``/``pair_center``/``pair_other`` ride
-        along so a device program can rebuild adjacency without touching
-        the host table again.
+        jitted dispatch.  ``n_events`` and ``n_changes`` count the pair
+        events the table was built from and those it keeps.
+        ``base``/``pair_center``/``pair_other`` ride along so a device
+        program can rebuild adjacency without touching the host table
+        again.
         """
         cached = getattr(self, "_device_export", None)
         if cached is not None:
             return cached
         evm = self.seq >= 0
         p = self.pair_id[evm]
-        counts = (np.bincount(p, minlength=self.n_pairs).astype(np.int64)
-                  if self.n_pairs else np.zeros(0, np.int64))
-        F = max(int(counts.max()) if len(counts) else 0, 1)
-        flip_t = np.full((self.n_pairs, F), np.iinfo(np.int64).max, np.int64)
-        flip_s = np.full((self.n_pairs, F), -1, np.int8)
+        st = self.st[evm]
+        # state before each event: the previous event's, or the pair's base
+        prev = np.empty_like(st)
         if len(p):
-            # table order is (pair-major, chronological): column index is
-            # the event's rank within its pair's run
-            col = np.arange(len(p)) - np.r_[0, np.cumsum(counts)][p]
-            flip_t[p, col] = self.t[evm]
-            flip_s[p, col] = self.st[evm]
+            first = np.r_[True, p[1:] != p[:-1]]
+            prev[1:] = st[:-1]
+            prev[first] = self.base[p[first]]
+        chg = st != prev
+        pc = p[chg]
+        counts = (np.bincount(pc, minlength=self.n_pairs).astype(np.int64)
+                  if self.n_pairs else np.zeros(0, np.int64))
+        C = max(int(counts.max()) if len(counts) else 0, 1)
+        chg_t = np.full((self.n_pairs, C), np.iinfo(np.int64).max, np.int64)
+        if len(pc):
+            # (pair-major, chronological): column is the change's rank
+            # within its pair's run
+            col = np.arange(len(pc)) - np.r_[0, np.cumsum(counts)][pc]
+            chg_t[pc, col] = self.t[evm][chg]
         cached = {
-            "flip_t": flip_t, "flip_s": flip_s,
+            "chg_t": chg_t, "n_events": len(p), "n_changes": len(pc),
             "base": self.base.astype(np.int8),
             "pair_center": self.pair_center.astype(np.int32),
             "pair_other": self.pair_other.copy(),

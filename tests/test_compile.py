@@ -5,8 +5,10 @@ style="kernel" device-operand cache."""
 import numpy as np
 import pytest
 
+from repro.core.events import EDGE_ADD, EDGE_DEL
 from repro.taf import TemporalQuery, compile as tc, replay
 from repro.taf.plan import PlanExecutor
+from repro.taf.son import SoTS
 
 from tests.test_replay import random_sots
 
@@ -21,6 +23,102 @@ def _both(q):
 
 def _ts(rng, t_max=40, T=20):
     return np.sort(rng.randint(0, t_max + 1, size=T)).astype(np.int64)
+
+
+def _pair_sots(runs, adj=(), N=8, t_max=40):
+    """SoTS of N nodes (ids 0..N-1), all present, with no attributes,
+    whose only events are the edge events ``runs`` — (center, t, kind,
+    other), chronological per center — over the initial adjacency
+    ``adj``, a list of (center, other) pairs."""
+    runs = sorted(runs, key=lambda r: (r[0], r[1]))  # stable: same-second order kept
+    center = np.array([r[0] for r in runs], np.int64)
+    adj = sorted(adj)
+    adj_c = np.array([a[0] for a in adj], np.int64)
+    return SoTS(
+        node_ids=np.arange(N, dtype=np.int32), t0=0, t1=t_max,
+        init_present=np.ones(N, np.int8),
+        init_attrs=np.zeros((N, 3), np.int32),
+        ev_indptr=np.searchsorted(center, np.arange(N + 1)).astype(np.int64),
+        ev_t=np.array([r[1] for r in runs], np.int64),
+        ev_kind=np.array([r[2] for r in runs], np.int8),
+        ev_key=np.full(len(runs), -1, np.int16),
+        ev_val=np.full(len(runs), -1, np.int32),
+        ev_other=np.array([r[3] for r in runs], np.int32),
+        adj_indptr=np.searchsorted(adj_c, np.arange(N + 1)).astype(np.int64),
+        adj_nbr=np.array([a[1] for a in adj], np.int32),
+        adj_val=np.full(len(adj), -1, np.int32),
+    )
+
+
+def _readd_operand(rng, t_max=40):
+    """Add-only, as an e-mail history: pair (0, 1) re-added 300 times from
+    each end, first at the window's first second and last at its last;
+    (2, 3), there from the start, re-added 200 times; (1, 2), (4, 5) and
+    (5, 6) added once, (4, 5) at the window's last second."""
+    runs = []
+    for c, o in ((0, 1), (1, 0)):
+        tt = np.sort(np.r_[0, t_max, rng.randint(0, t_max + 1, 298)])
+        runs += [(c, int(t), EDGE_ADD, o) for t in tt]
+    for c, o in ((2, 3), (3, 2)):
+        runs += [(c, int(t), EDGE_ADD, o)
+                 for t in np.sort(rng.randint(0, t_max + 1, 200))]
+    runs += [(1, 17, EDGE_ADD, 2), (4, t_max, EDGE_ADD, 5),
+             (6, 9, EDGE_ADD, 5)]
+    ts = np.sort(np.r_[0, 16, 17, t_max - 1, t_max,
+                       rng.randint(0, t_max + 1, 13)]).astype(np.int64)
+    return _pair_sots(runs, adj=[(2, 3), (3, 2)], t_max=t_max), ts
+
+
+def _toggle_operand(rng, t_max=40):
+    """Adds and deletes that toggle: same-second runs that end where they
+    began or one change further, toggles across seconds, deletes of dead
+    pairs and re-adds of live ones, from one end or both."""
+    runs = [(0, 5, EDGE_ADD, 1), (0, 5, EDGE_DEL, 1), (0, 5, EDGE_ADD, 1),
+            (0, 10, EDGE_DEL, 1), (0, 10, EDGE_ADD, 1), (0, 12, EDGE_ADD, 1),
+            (0, 20, EDGE_DEL, 1), (0, 20, EDGE_DEL, 1), (0, 21, EDGE_ADD, 1),
+            (0, 22, EDGE_DEL, 1), (0, 23, EDGE_ADD, 1),
+            (1, 7, EDGE_DEL, 0), (1, 7, EDGE_ADD, 0), (1, 30, EDGE_DEL, 0),
+            (2, 3, EDGE_DEL, 3), (2, 3, EDGE_DEL, 3), (2, 8, EDGE_ADD, 3),
+            (2, 8, EDGE_DEL, 3)]
+    for c, o in ((4, 5), (5, 6), (6, 4)):
+        tt = np.sort(rng.randint(0, t_max + 1, 60))
+        tt[1::3] = tt[0::3][: len(tt[1::3])]  # same-second runs
+        kind = rng.choice([EDGE_ADD, EDGE_DEL], size=60)
+        runs += [(c, int(t), int(k), o) for t, k in zip(np.sort(tt), kind)]
+    ts = np.sort(np.r_[3, 5, 7, 8, 10, 20, 21, 22, 23, 30,
+                       rng.randint(0, t_max + 1, 8)]).astype(np.int64)
+    return _pair_sots(runs, adj=[(1, 0), (6, 4)], t_max=t_max), ts
+
+
+# parity operands beyond the random ones, by name
+SPECIAL = {"readd": _readd_operand, "toggle": _toggle_operand}
+
+
+def _operand(case, offset):
+    """(sots, ts) of a parity case: a random operand drawn from the
+    RandomState of ``offset + case``, or the named special operand."""
+    if case in SPECIAL:
+        return SPECIAL[case](np.random.RandomState(offset))
+    rng = np.random.RandomState(offset + case)
+    return random_sots(rng, N=rng.randint(4, 12)), _ts(rng, T=18)
+
+
+def _pair_changes(sots):
+    """(existence changes per directed (center row, other id) pair, pair
+    events) by a plain replay of each center's edge events."""
+    state, changes, n_events = {}, {}, 0
+    for i in range(len(sots)):
+        for o in sots.neighbors_of(i)[0]:
+            state[(i, int(o))] = 1
+    for i in range(len(sots)):
+        for j in range(sots.ev_indptr[i], sots.ev_indptr[i + 1]):
+            if sots.ev_kind[j] not in (EDGE_ADD, EDGE_DEL):
+                continue
+            pair, new = (i, int(sots.ev_other[j])), int(sots.ev_kind[j] == EDGE_ADD)
+            n_events += 1
+            changes[pair] = changes.get(pair, 0) + (state.get(pair, 0) != new)
+            state[pair] = new
+    return changes, n_events
 
 
 # ---------------------------------------------------------------------------
@@ -42,13 +140,11 @@ def test_fused_slice_bit_identical_randomized(seed):
     assert fused.value["attrs"].dtype == staged.value["attrs"].dtype
 
 
-@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("seed", [*range(4), *SPECIAL])
 def test_fused_pagerank_matches_staged_randomized(seed):
     """Float op: identical math, f32 device vs f64 host — documented
     tolerance (docs/api.md), not bit parity."""
-    rng = np.random.RandomState(100 + seed)
-    sots = random_sots(rng, N=rng.randint(4, 12))
-    ts = _ts(rng, T=18)
+    sots, ts = _operand(seed, 100)
     q = TemporalQuery.over(sots).node_compute(
         tc.pagerank(iters=8), style="temporal", points=ts)
     fused, staged = _both(q)
@@ -57,11 +153,9 @@ def test_fused_pagerank_matches_staged_randomized(seed):
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("seed", [*range(4), *SPECIAL])
 def test_fused_components_bit_identical_randomized(seed):
-    rng = np.random.RandomState(200 + seed)
-    sots = random_sots(rng, N=rng.randint(4, 12))
-    ts = _ts(rng, T=18)
+    sots, ts = _operand(seed, 200)
     q = TemporalQuery.over(sots).node_compute(
         tc.components(iters=12), style="temporal", points=ts)
     fused, staged = _both(q)
@@ -69,16 +163,39 @@ def test_fused_components_bit_identical_randomized(seed):
     np.testing.assert_array_equal(fused.value[1], staged.value[1])
 
 
-@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("seed", [*range(4), *SPECIAL])
 def test_fused_triangles_bit_identical_randomized(seed):
-    rng = np.random.RandomState(300 + seed)
-    sots = random_sots(rng, N=rng.randint(4, 12))
-    ts = _ts(rng, T=18)
+    sots, ts = _operand(seed, 300)
     q = TemporalQuery.over(sots).node_compute(
         tc.triangles(), style="temporal", points=ts)
     fused, staged = _both(q)
     assert any("fused compute[triangles]" in n for n in fused.notes)
     np.testing.assert_array_equal(fused.value[1], staged.value[1])
+
+
+@pytest.mark.parametrize("case", [*range(4), *SPECIAL])
+def test_edge_export_keeps_only_existence_changes(case):
+    """The edge operand holds each pair's existence changes and no other
+    event: its width is the most changes of any pair, the counters add
+    the events seen and the changes kept, and the parity of the changes
+    at or before t gives the host replay's existence."""
+    sots, ts = _operand(case, 400)
+    changes, n_events = _pair_changes(sots)
+    before = dict(tc.STATS)
+    res = TemporalQuery.over(sots).node_compute(
+        tc.components(iters=12), style="temporal", points=ts).run()
+    assert any("fused compute" in n for n in res.notes), res.notes
+    assert tc.STATS["flip_events"] - before["flip_events"] == n_events
+    assert (tc.STATS["flip_changes"] - before["flip_changes"]
+            == sum(changes.values()))
+    er = replay.edge_replay(sots)
+    exp = er.device_export()
+    assert exp["chg_t"].shape[1] == max([1, *changes.values()])
+    if case == "readd":  # hundreds of events, at most one change a pair
+        assert exp["chg_t"].shape[1] == 1 and n_events > 1000
+    cnt = (exp["chg_t"][:, :, None] <= ts[None, None, :]).sum(axis=1)
+    np.testing.assert_array_equal(exp["base"][:, None] ^ (cnt & 1),
+                                  er.exist_matrix(ts))
 
 
 @pytest.mark.parametrize("mk,exact", [

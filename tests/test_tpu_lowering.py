@@ -29,9 +29,10 @@ H, P, S, K, T = 6, 8, 4096, 4, 16
 
 # chip_smoke.py's fused-analytics operand at 250k events, seed 0 (window
 # = last quarter): N members, Emax events per member row and pair-table
-# rows as the smoke prints them; flips per pair row and canonical edges
-# (at most half the pair rows) are upper estimates; 64 timepoints
-SMOKE_N, SMOKE_EMAX, SMOKE_PAIRS, SMOKE_FLIPS, SMOKE_E, SMOKE_T = (
+# rows as the smoke prints them; existence changes per pair row and
+# canonical edges (at most half the pair rows) are upper estimates; 64
+# timepoints
+SMOKE_N, SMOKE_EMAX, SMOKE_PAIRS, SMOKE_CHANGES, SMOKE_E, SMOKE_T = (
     25_336, 374, 213_924, 4, 107_000, 64)
 
 
@@ -90,20 +91,36 @@ def test_motif_kernel_lowers_at_largest_fused_n(spec):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_pagerank_program_lowers_at_smoke_shapes(spec):
+def _pagerank_program(spec, pairs, changes, edges):
+    """The fused PageRank program compiled over smoke-sized node rows and
+    an edge operand of the given pair rows, change columns and edges."""
     i32 = jnp.int32
     node = {k: spec((SMOKE_N, SMOKE_EMAX), i32)
             for k in ("ev_t", "ev_kind", "ev_key", "ev_val")}
     node["init_present"] = spec((SMOKE_N,), i32)
     node["init_attrs"] = spec((SMOKE_N, K), i32)
-    edge = {"flip_t": spec((SMOKE_PAIRS, SMOKE_FLIPS), i32),
-            "flip_s": spec((SMOKE_PAIRS, SMOKE_FLIPS), i32),
-            "base": spec((SMOKE_PAIRS,), i32),
-            "edge_valid": spec((SMOKE_E,), jnp.float32)}
+    edge = {"chg_t": spec((pairs, changes), i32),
+            "base": spec((pairs,), i32),
+            "edge_valid": spec((edges,), jnp.float32)}
     for k in ("edge_u", "edge_v", "pair_a", "pair_b"):
-        edge[k] = spec((SMOKE_E,), i32)
+        edge[k] = spec((edges,), i32)
     for k in ("frow", "fcol", "feid"):
-        edge[k] = spec((2 * SMOKE_E,), i32)
+        edge[k] = spec((2 * edges,), i32)
     prog = tc._build_series_program(tc.pagerank())
-    compiled = prog.lower(node, edge, spec((SMOKE_T,), i32)).compile()
+    return prog.lower(node, edge, spec((SMOKE_T,), i32)).compile()
+
+
+def test_fused_pagerank_program_lowers_at_smoke_shapes(spec):
+    compiled = _pagerank_program(spec, SMOKE_PAIRS, SMOKE_CHANGES, SMOKE_E)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+def test_edge_liveness_fuses_at_a_wide_change_table(spec):
+    """A deletion-heavy history gives a wide change table.  The per-row
+    compare-and-sum must fuse into its reduction: at C = 512 the program
+    holds less than one more (P, T) plane than at C = 1, where an
+    unfused compare would hold P * C * T int32s (6.5 GB)."""
+    pairs, changes, edges = 50_000, 512, 25_000
+    temp = {c: _pagerank_program(spec, pairs, c, edges)
+            .memory_analysis().temp_size_in_bytes for c in (1, changes)}
+    assert temp[changes] - temp[1] < pairs * SMOKE_T * 4
