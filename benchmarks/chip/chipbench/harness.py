@@ -6,10 +6,18 @@ check the answers against the plain reference, print the result line.
 
 Everything a cell is made of is found by name under
 ``benchmarks/chip/``: the cell in ``BENCHMARK.json``; its configuration
-in ``configs/``; its traffic mix in ``traffic/<mix>.json``, whose
-``kind`` names the module ``traffic/<kind>.py`` that turns it into
-requests; each operation the mix names in ``operations/<op>.py``; and
-each metric's reader in ``metrics/<name>.py``.
+in ``configs/``, whose ``history`` (``interactions`` where it names
+none) names the module ``histories/<history>.py`` that generates its
+event log; its traffic mix in ``traffic/<mix>.json``, whose ``kind``
+names the module ``traffic/<kind>.py`` that turns it into requests;
+each operation the mix names in ``operations/<op>.py``; and each
+metric's reader in ``metrics/<name>.py``.
+
+A history module exports ``history(cfg)``, the event columns in the
+store's layout (``t, kind, src, dst, key, val``) sorted by time, with
+``counts`` of what was made; and ``tails(cols, spans=())``, the tail
+statistics of its own choosing that set the operand shapes, over the
+whole history and in each ``(lo, hi)`` span under ``"windows"``.
 """
 from __future__ import annotations
 
@@ -25,7 +33,7 @@ from pathlib import Path
 
 import numpy as np
 
-from chipbench import gen, tracing
+from chipbench import tracing
 from reference.analytics import Windows
 from reference.replay import History
 
@@ -59,6 +67,16 @@ def reader(metrics_dir: Path, name: str):
     return load_module(path, "chipbench_metric_").read
 
 
+def load_history(chip: Path, cfg: dict):
+    """The configuration's history module, ``histories/<history>.py``
+    (``interactions`` where the configuration names none)."""
+    name = cfg.get("history", "interactions")
+    path = chip / "histories" / f"{name}.py"
+    if not path.exists():
+        raise SystemExit(f"unknown history {name!r}: there is no {path}")
+    return load_module(path, "chipbench_history_")
+
+
 def load_cell(root: Path, name: str) -> dict:
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -69,6 +87,7 @@ def load_cell(root: Path, name: str) -> dict:
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     cfg = json.loads((root / conf["file"]).read_text())
     chip = root / "benchmarks" / "chip"
+    history = load_history(chip, cfg)
     mix = json.loads((chip / "traffic" / f"{cell['traffic']}.json").read_text())
     kind = load_module(chip / "traffic" / f"{mix['kind']}.py",
                        "chipbench_kind_")
@@ -78,7 +97,8 @@ def load_cell(root: Path, name: str) -> dict:
     def listed(m):
         return "workloads" not in m or name in m["workloads"]
 
-    return {"cell": cell, "cfg": cfg, "mix": mix, "kind": kind, "ops": ops,
+    return {"cell": cell, "cfg": cfg, "history": history, "mix": mix,
+            "kind": kind, "ops": ops,
             "end_to_end": [m for m in bench["end_to_end"] if listed(m)],
             "per_layer": [m for m in bench["per_layer"] if listed(m)],
             "metrics_dir": chip / "metrics"}
@@ -102,19 +122,19 @@ def start_device(chips: int, require_tpu: bool) -> dict:
             "count": len(devs), "devices": devs[:chips], "cache": cache}
 
 
-def build(cfg: dict):
+def build(cfg: dict, history):
     from repro.core.events import EventLog
     from repro.taf import HistoricalGraphStore
 
     t = time.perf_counter()
-    h = gen.history(cfg)
+    h = history.history(cfg)
     gen_s = time.perf_counter() - t
     published = {k: v for k, v in cfg["published"].items()
                  if not isinstance(v, dict)}
     log(f"generated: {json.dumps(h['counts'])} (published "
         f"{json.dumps(published)})")
-    log(f"tails: {json.dumps(gen.tails(h['cols']))} (configured "
-        f"{json.dumps(cfg['assumed'].get('tails'))})")
+    log(f"tails: {json.dumps(history.tails(h['cols']))} (configured "
+        f"{json.dumps(cfg.get('assumed', {}).get('tails'))})")
     t = time.perf_counter()
     store = HistoricalGraphStore.build(EventLog(**h["cols"]), **cfg["store"])
     return h, store, gen_s, time.perf_counter() - t
@@ -236,10 +256,11 @@ def main(argv=None, root: Path = None, require_tpu: bool = True) -> int:
     # an unknown chip is an error; off the chip (tests) there is no peak
     peak = peaks.peaks(dev["kind"]) if require_tpu else None
     device_s = time.perf_counter() - t_setup
-    h, store, gen_s, build_s = build(cfg)
+    h, store, gen_s, build_s = build(cfg, spec["history"])
     time_range = store.time_range()
     windows = kind.windows(mix, time_range)
-    log(f"window tails: {json.dumps(gen.tails(h['cols'], windows).get('windows', []))}")
+    tails = spec["history"].tails(h["cols"], windows)
+    log(f"window tails: {json.dumps(tails.get('windows', []))}")
     n_events = len(h["cols"]["t"])
     t = time.perf_counter()
     traces0 = tc.STATS["traces"]

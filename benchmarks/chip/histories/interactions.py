@@ -1,5 +1,6 @@
-"""Vectorised generator of a temporal interaction network from its
-configuration file.
+"""The ``interactions`` history: a vectorised generator of a temporal
+interaction network from its configuration file.  It is the history of
+every configuration that names none (``"history"`` in the file).
 
 The history (which pairs interact, how often, and when) is drawn from
 the configuration alone, with its ``assumed.structure_seed``, to the

@@ -125,7 +125,7 @@ def test_traced_reads_add_up_on_a_real_trace(tmp_path, monkeypatch, capsys):
 
     root = tmp_path / "root"
     chip = root / "benchmarks" / "chip"
-    for d in ("metrics", "operations", "traffic"):
+    for d in ("histories", "metrics", "operations", "traffic"):
         shutil.copytree(HERE / d, chip / d,
                         ignore=shutil.ignore_patterns("__pycache__"))
     (chip / "configs").mkdir()
