@@ -78,6 +78,9 @@ STATS: Dict[str, int] = {
     "operand_uploads": 0,  # device-resident operand exports built
     "flip_events": 0,      # pair events seen by the edge-operand exports
     "flip_changes": 0,     # of them, existence changes the exports keep
+    "node_events": 0,      # real events in the node-operand exports' tables
+    "node_slots": 0,       # their slots, N x Emax each
+    "node_deletes": 0,     # NODE_DEL events among the real ones
 }
 
 _PROGRAM_CACHE_MAX = 64
@@ -400,15 +403,19 @@ def _node_arrays(son: SoN):
     import jax.numpy as jnp
 
     STATS["operand_uploads"] += 1
-    pads = son.padded_events()
-    arrs = {
-        "ev_t": jnp.asarray(pads["t"]),
-        "ev_kind": jnp.asarray(pads["kind"].astype(np.int32)),
-        "ev_key": jnp.asarray(pads["key"].astype(np.int32)),
-        "ev_val": jnp.asarray(pads["val"]),
-        "init_present": jnp.asarray(son.init_present.astype(np.int32)),
-        "init_attrs": jnp.asarray(son.init_attrs),
-    }
+    with trace.span("compile.export_node"):
+        pads = son.padded_events()
+        STATS["node_events"] += int((pads["kind"] >= 0).sum())
+        STATS["node_slots"] += pads["kind"].size
+        STATS["node_deletes"] += int((pads["kind"] == NODE_DEL).sum())
+        arrs = {
+            "ev_t": jnp.asarray(pads["t"]),
+            "ev_kind": jnp.asarray(pads["kind"].astype(np.int32)),
+            "ev_key": jnp.asarray(pads["key"].astype(np.int32)),
+            "ev_val": jnp.asarray(pads["val"]),
+            "init_present": jnp.asarray(son.init_present.astype(np.int32)),
+            "init_attrs": jnp.asarray(son.init_attrs),
+        }
     _operands.put(key, arrs, owner=son)
     return arrs
 
@@ -418,10 +425,19 @@ def _edge_arrays(sots: SoTS):
     hit = _operands.get(key, owner=sots)
     if hit is not None:
         return hit
+    STATS["operand_uploads"] += 1
+    with trace.span("compile.export_edge"):
+        arrs = _export_edge(sots)
+    _operands.put(key, arrs, owner=sots)
+    return arrs
+
+
+def _export_edge(sots: SoTS):
+    """The edge operand's device arrays, from the pair table of existence
+    changes (``EdgeReplay.device_export``) and the canonical edges."""
     import jax
     import jax.numpy as jnp
 
-    STATS["operand_uploads"] += 1
     N = len(sots)
     er = replay.edge_replay(sots)
     exp = er.device_export()
@@ -480,7 +496,6 @@ def _edge_arrays(sots: SoTS):
         "feid": jnp.asarray(feid[o]),
         "n_real_edges": len(uniq),
     }
-    _operands.put(key, arrs, owner=sots)
     return arrs
 
 

@@ -5,7 +5,7 @@ style="kernel" device-operand cache."""
 import numpy as np
 import pytest
 
-from repro.core.events import EDGE_ADD, EDGE_DEL
+from repro.core.events import EDGE_ADD, EDGE_DEL, NODE_ADD, NODE_DEL
 from repro.taf import TemporalQuery, compile as tc, replay
 from repro.taf.plan import PlanExecutor
 from repro.taf.son import SoTS
@@ -196,6 +196,28 @@ def test_edge_export_keeps_only_existence_changes(case):
     cnt = (exp["chg_t"][:, :, None] <= ts[None, None, :]).sum(axis=1)
     np.testing.assert_array_equal(exp["base"][:, None] ^ (cnt & 1),
                                   er.exist_matrix(ts))
+
+
+@pytest.mark.parametrize("case", ["deletes", *range(3), *SPECIAL])
+def test_node_export_counts_events_slots_and_deletes(case):
+    """The node operand's counters add the real events of its padded
+    table, the table's slots (N x the most events of one node) and the
+    NODE_DELs among the events, once per export."""
+    if case == "deletes":  # node 0 leaves and returns, node 1 leaves
+        sots = _pair_sots([(0, 5, NODE_DEL, -1), (0, 9, NODE_ADD, -1),
+                           (1, 3, EDGE_ADD, 2), (2, 3, EDGE_ADD, 1),
+                           (1, 7, NODE_DEL, -1), (1, 8, EDGE_DEL, 2)], N=4)
+        want = {"node_events": 6, "node_slots": 4 * 3, "node_deletes": 2}
+    else:
+        sots, _ = _operand(case, 500)
+        per_node = np.diff(sots.ev_indptr)
+        want = {"node_events": len(sots.ev_t),
+                "node_slots": len(sots) * max(int(per_node.max()), 1),
+                "node_deletes": int((sots.ev_kind == NODE_DEL).sum())}
+    before = dict(tc.STATS)
+    tc._node_arrays(sots)
+    tc._node_arrays(sots)  # served from the operand cache: counted once
+    assert {k: tc.STATS[k] - before[k] for k in want} == want
 
 
 @pytest.mark.parametrize("mk,exact", [

@@ -129,5 +129,7 @@ def test_fused_pagerank_query_spans_nest_by_layer(store, tmp_path):
     assert "plan.fetch" in got["tgi.read_guard"]
     assert "plan.fetch" in got["tgi.get_snapshot"]
     assert got["compile.dispatch"] == {"plan.run"}
+    assert got["compile.export_node"] == {"compile.dispatch"}
+    assert got["compile.export_edge"] == {"compile.dispatch"}
     assert got["compile.readback"] == {"plan.run"}
     assert got["replay.state_at_many"] == {"plan.run"}
