@@ -7,18 +7,22 @@ per operator.  This module lowers the terminal stage of a validated Plan
 over the batched-replay arrays:
 
 * ``Slice([t1..tT])``                — the device ``state_at_many``: per-
-  node presence/attrs at every timepoint from ``SoN.padded_events()``
-  (searchsorted + cumulative last-write index per row), bit-identical to
-  the host replay engine;
+  node presence/attrs at every timepoint, bit-identical to the host
+  replay engine (attrs by searchsorted + cumulative last-write index
+  per row of ``SoN.padded_events()``);
 * ``Compute(style="temporal", fn=<FusedOp>)`` — the temporal-analytics
   kernel family (``pagerank``/``components``/``triangles``) over
   ``EdgeReplay``'s pair table, exported once per operand via
   ``EdgeReplay.device_export()`` as a table of each pair's existence
-  changes and kept device-resident: existence at t is the pair's base
-  state XOR the parity of its changes at or before t (a compare-and-sum
-  per row, no search);
+  changes and kept device-resident;
 * ``Evolution(fn=<FusedScalarOp>)``  — the same per-node programs with a
   per-timepoint reduction folded into the jit.
+
+Presence and existence both come from tables of state changes
+(``replay.change_table``): a node's presence changes
+(``replay.node_presence_changes``) and a pair's existence changes.  The
+state at t is the row's base state XOR the parity of its changes at or
+before t — a compare and a parity reduction per row, with no search.
 
 Programs are cached keyed on plan *shape* — stage kind, op identity and
 static params, operand array shapes/dtypes, and T — so repeated queries
@@ -50,7 +54,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from repro import trace
-from repro.core.events import NATTR_SET, NODE_ADD, NODE_DEL
+from repro.core.events import NATTR_SET, NODE_DEL
 from repro.taf import operators as ops
 from repro.taf import replay
 from repro.taf.son import SoN, SoTS
@@ -78,9 +82,10 @@ STATS: Dict[str, int] = {
     "operand_uploads": 0,  # device-resident operand exports built
     "flip_events": 0,      # pair events seen by the edge-operand exports
     "flip_changes": 0,     # of them, existence changes the exports keep
-    "node_events": 0,      # real events in the node-operand exports' tables
-    "node_slots": 0,       # their slots, N x Emax each
-    "node_deletes": 0,     # NODE_DEL events among the real ones
+    "node_events": 0,      # presence changes in the node-operand exports' tables
+    "node_slots": 0,       # their slots, N x C each
+    "node_events_seen": 0,  # every event of the rows those exports read
+    "node_deletes": 0,     # NODE_DEL events among the events read
 }
 
 _PROGRAM_CACHE_MAX = 64
@@ -396,6 +401,8 @@ def max_pagerank(damping: float = 0.85, iters: int = 20) -> FusedScalarOp:
 
 
 def _node_arrays(son: SoN):
+    """The node operand's device arrays: each node's presence changes
+    (``replay.node_presence_changes``) and its presence at t0."""
     key = (replay.operand_key(son), "node")
     hit = _operands.get(key, owner=son)
     if hit is not None:
@@ -404,18 +411,40 @@ def _node_arrays(son: SoN):
 
     STATS["operand_uploads"] += 1
     with trace.span("compile.export_node"):
-        pads = son.padded_events()
-        STATS["node_events"] += int((pads["kind"] >= 0).sum())
-        STATS["node_slots"] += pads["kind"].size
-        STATS["node_deletes"] += int((pads["kind"] == NODE_DEL).sum())
+        exp = replay.node_presence_changes(son)
+        STATS["node_events"] += exp["n_changes"]
+        STATS["node_slots"] += exp["pres_t"].size
+        STATS["node_events_seen"] += exp["n_events"]
+        STATS["node_deletes"] += exp["n_deletes"]
         arrs = {
-            "ev_t": jnp.asarray(pads["t"]),
-            "ev_kind": jnp.asarray(pads["kind"].astype(np.int32)),
-            "ev_key": jnp.asarray(pads["key"].astype(np.int32)),
-            "ev_val": jnp.asarray(pads["val"]),
-            "init_present": jnp.asarray(son.init_present.astype(np.int32)),
-            "init_attrs": jnp.asarray(son.init_attrs),
+            "pres_t": jnp.asarray(_device_times(exp["pres_t"])),
+            "init_present": jnp.asarray(exp["base"].astype(np.int32)),
         }
+    _operands.put(key, arrs, owner=son)
+    return arrs
+
+
+def _slice_arrays(son: SoN):
+    """The fused slice's operand: the node operand plus the padded
+    (N, Emax) table of every event that ``_dev_attrs`` searches."""
+    key = (replay.operand_key(son), "slice")
+    hit = _operands.get(key, owner=son)
+    if hit is not None:
+        return hit
+    import jax.numpy as jnp
+
+    node = _node_arrays(son)
+    STATS["operand_uploads"] += 1
+    with trace.span("compile.export_node"):
+        pads = son.padded_events()
+        arrs = dict(
+            node,
+            ev_t=jnp.asarray(pads["t"]),
+            ev_kind=jnp.asarray(pads["kind"].astype(np.int32)),
+            ev_key=jnp.asarray(pads["key"].astype(np.int32)),
+            ev_val=jnp.asarray(pads["val"]),
+            init_attrs=jnp.asarray(son.init_attrs),
+        )
     _operands.put(key, arrs, owner=son)
     return arrs
 
@@ -435,7 +464,6 @@ def _edge_arrays(sots: SoTS):
 def _export_edge(sots: SoTS):
     """The edge operand's device arrays, from the pair table of existence
     changes (``EdgeReplay.device_export``) and the canonical edges."""
-    import jax
     import jax.numpy as jnp
 
     N = len(sots)
@@ -447,10 +475,6 @@ def _export_edge(sots: SoTS):
     if er.n_pairs == 0:  # dummy never-existing pair keeps gathers in-bounds
         chg_t = np.full((1, 1), np.iinfo(np.int64).max, np.int64)
         base = np.zeros(1, np.int8)
-    # re-sentinel the int64-max pads in the device's integer dtype (they
-    # would wrap under jax's default int32, as in ``degree_series_kernel``)
-    big = np.iinfo(jax.dtypes.canonicalize_dtype(np.int64)).max
-    chg_t = np.where(chg_t == np.iinfo(np.int64).max, big, chg_t)
     v = replay.member_rows(exp["pair_other"], sots.node_ids).astype(np.int64)
     u = exp["pair_center"].astype(np.int64)
     valid = (v >= 0) & (u != v)
@@ -484,7 +508,7 @@ def _export_edge(sots: SoTS):
     feid = np.concatenate([np.arange(E), np.arange(E)]).astype(np.int32)
     o = np.argsort(frow, kind="stable")
     arrs = {
-        "chg_t": jnp.asarray(chg_t),
+        "chg_t": jnp.asarray(_device_times(chg_t)),
         "base": jnp.asarray(base.astype(np.int32)),
         "edge_u": jnp.asarray(eu),
         "edge_v": jnp.asarray(ev_),
@@ -499,31 +523,38 @@ def _export_edge(sots: SoTS):
     return arrs
 
 
+def _device_times(t: np.ndarray) -> np.ndarray:
+    """A change table's times with its int64-max pads re-sentinelled in
+    the device's integer dtype (they would wrap under jax's default
+    int32, as in ``degree_series_kernel``)."""
+    import jax
+
+    big = np.iinfo(jax.dtypes.canonicalize_dtype(np.int64)).max
+    return np.where(t == np.iinfo(np.int64).max, big, t)
+
+
 # ---------------------------------------------------------------------------
 # Device programs (jnp; shared by every covered plan shape)
 # ---------------------------------------------------------------------------
 
 
-def _dev_presence(jnp, node, tsv):
-    """(N, T) int32 presence — the device ``state_at_many`` presence
-    half.  Pad slots are re-sentineled in-dtype (the host int64-max pad
-    wraps under jax's default int32, as in ``degree_series_kernel``)."""
+def _dev_parity(jnp, chg_t, base, tsv):
+    """(R, T) int32 state of R rows from the padded table of their state
+    changes (``replay.change_table``; pads at the dtype's max): changes
+    alternate, so a row's state at t is ``base XOR (number of changes
+    <= t) mod 2`` — the compare XOR-reduced over the C columns, which
+    fuses into its consumers at any C, with no search and no gather."""
     import jax
 
-    ev_t, kind = node["ev_t"], node["ev_kind"]
-    big = jnp.iinfo(ev_t.dtype).max
-    ev_t_s = jnp.where(kind < 0, big, ev_t)
-    cnt = jax.vmap(lambda row: jnp.searchsorted(row, tsv, side="right"))(ev_t_s)
-    E = ev_t.shape[1]
-    rank = jnp.broadcast_to(jnp.arange(E, dtype=jnp.int32)[None, :],
-                            ev_t.shape)
-    pmask = (kind == NODE_ADD) | (kind == NODE_DEL) | (kind == NATTR_SET)
-    plast = jax.lax.cummax(jnp.where(pmask, rank, -1), axis=1)
-    pidx = jnp.take_along_axis(plast, jnp.maximum(cnt - 1, 0), axis=1)
-    pidx = jnp.where(cnt > 0, pidx, -1)
-    kind_at = jnp.take_along_axis(kind, jnp.maximum(pidx, 0), axis=1)
-    return jnp.where(pidx >= 0, (kind_at != NODE_DEL).astype(jnp.int32),
-                     node["init_present"][:, None])
+    odd = jax.lax.reduce(chg_t[:, :, None] <= tsv[None, None, :],
+                         np.bool_(False), jax.lax.bitwise_xor, (1,))
+    return base[:, None] ^ odd.astype(jnp.int32)
+
+
+def _dev_presence(jnp, node, tsv):
+    """(N, T) int32 presence — the device ``state_at_many`` presence
+    half, from each node's presence changes."""
+    return _dev_parity(jnp, node["pres_t"], node["init_present"], tsv)
 
 
 def _dev_attrs(jnp, node, tsv, cnt_cache=None):
@@ -557,17 +588,12 @@ def _dev_attrs(jnp, node, tsv, cnt_cache=None):
 
 def _dev_edge_live(jnp, edge, act, tsv):
     """(E, T) f32 edge liveness from the padded table of existence
-    changes: a pair's changes alternate its state, so it exists at t iff
-    ``base XOR (number of changes <= t) mod 2`` — one compare-and-sum
-    over the C change columns of each row, no search and no gather.  The
-    <=2 directed pair rows are OR-folded by contiguous-row gather and
-    masked by both endpoints' presence.  ``act`` is (N, T) f32 —
-    everything stays (entity, T)-major so propagation scatters move
-    whole contiguous T-rows."""
-    chg_t = edge["chg_t"]  # (P, C), pads at the dtype's max
-    cnt = jnp.sum((chg_t[:, :, None] <= tsv[None, None, :]).astype(jnp.int32),
-                  axis=1)  # (P, T)
-    pair_live = (edge["base"][:, None] ^ (cnt & 1)).astype(jnp.float32)
+    changes (``_dev_parity``).  The <=2 directed pair rows are OR-folded
+    by contiguous-row gather and masked by both endpoints' presence.
+    ``act`` is (N, T) f32 — everything stays (entity, T)-major so
+    propagation scatters move whole contiguous T-rows."""
+    pair_live = _dev_parity(jnp, edge["chg_t"], edge["base"],
+                            tsv).astype(jnp.float32)  # (P, T)
     el = jnp.maximum(pair_live[edge["pair_a"]], pair_live[edge["pair_b"]])
     el = el * edge["edge_valid"][:, None]
     return el * act[edge["edge_u"]] * act[edge["edge_v"]]
@@ -699,7 +725,7 @@ def _fused_slice(operand, stage, replay_cache):
                      for kk, vv in hit.items()}
             return value, ("compile: fused slice (replay-LRU hit)",)
     with trace.span("compile.dispatch"):
-        node = _node_arrays(operand)
+        node = _slice_arrays(operand)
         key = ("slice", _shape_sig(node), T)
         hit_before = key in _programs
         prog = _get_program(key, _build_slice_program)

@@ -24,6 +24,10 @@ Three engines live here:
                        both engines (the state extraction under
                        density/LCC/PageRank-over-time series).
 
+``change_table`` lays out the fused programs' device operands: each row's
+state changes alone (a pair's existence, ``EdgeReplay.device_export``; a
+node's presence, ``node_presence_changes``), read by parity.
+
 ``ReplayCache`` is the small LRU the plan executor keys on
 ``(operand identity, timepoints)`` so repeated slices of the same
 operand don't replay at all.  ``STATS`` counts engine invocations —
@@ -165,6 +169,66 @@ def state_at_many(son: SoN, ts) -> Tuple[np.ndarray, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
+# Tables of state changes (the fused programs' device layout)
+# ---------------------------------------------------------------------------
+
+
+def change_table(row: np.ndarray, st: np.ndarray, t: np.ndarray,
+                 base: np.ndarray, n_rows: int) -> Tuple[np.ndarray, int]:
+    """``(chg_t (n_rows, C), n_changes)``: of each row's writes, only those
+    that change its 0/1 state.
+
+    ``row``/``st``/``t`` are the writes (row-major, chronological within a
+    row, same-second order kept), ``base`` each row's state before them.
+    A write is kept when its state differs from the one before it.  The
+    kept times fill a dense table, chronological within each row (C = the
+    most changes of one row, at least 1; pad = int64 max).  Changes
+    alternate, so the state at t is ``base XOR (number of changes <= t)
+    mod 2``: a compare and a parity per row, with no search.
+    """
+    prev = np.empty_like(st)
+    if len(row):
+        first = np.r_[True, row[1:] != row[:-1]]
+        prev[1:] = st[:-1]
+        prev[first] = base[row[first]]
+    chg = st != prev
+    rc = row[chg]
+    counts = (np.bincount(rc, minlength=n_rows).astype(np.int64)
+              if n_rows else np.zeros(0, np.int64))
+    C = max(int(counts.max()) if len(counts) else 0, 1)
+    chg_t = np.full((n_rows, C), np.iinfo(np.int64).max, np.int64)
+    if len(rc):
+        # column = the change's rank within its row's run
+        col = np.arange(len(rc)) - np.r_[0, np.cumsum(counts)][rc]
+        chg_t[rc, col] = t[chg]
+    return chg_t, len(rc)
+
+
+def node_presence_changes(son: SoN) -> Dict[str, np.ndarray]:
+    """Each node's presence changes, as ``change_table`` lays them out.
+
+    Presence depends only on ``NODE_ADD``, ``NODE_DEL`` and ``NATTR_SET``;
+    each sets it to ``kind != NODE_DEL``, as in ``state_at_many``.  Of a
+    row's such events only those that change its presence are kept, the
+    state before the first being ``init_present``; a re-add of a present
+    node or a delete of an absent one goes.  The parity then gives
+    ``state_at_many``'s presence bit for bit, same-second runs included.
+    ``n_events`` counts every event of the rows read, ``n_changes`` the
+    changes kept and ``n_deletes`` the ``NODE_DEL``s read.
+    """
+    kind = son.ev_kind
+    pidx = np.nonzero((kind == NODE_ADD) | (kind == NODE_DEL)
+                      | (kind == NATTR_SET))[0]
+    base = son.init_present.astype(np.int8)
+    pres_t, n_changes = change_table(
+        son.node_of_events()[pidx], (kind[pidx] != NODE_DEL).astype(np.int8),
+        son.ev_t[pidx], base, len(son))
+    return {"pres_t": pres_t, "base": base, "n_events": len(son.ev_t),
+            "n_changes": n_changes,
+            "n_deletes": int((kind == NODE_DEL).sum())}
+
+
+# ---------------------------------------------------------------------------
 # Edge replay: (center, neighbor) pair table over a SoTS
 # ---------------------------------------------------------------------------
 
@@ -280,47 +344,26 @@ class EdgeReplay:
         """Device-friendly padded table of existence changes (cached per
         EdgeReplay).
 
-        Of each pair's chronological events only those that change its
-        state are kept: the first that differs from ``base``, then each
-        that differs from the one before.  Events that re-add a live edge
-        or delete a dead one cannot change existence at any timepoint, so
-        they go.  The kept runs become a dense ``chg_t (n_pairs, C)``
-        array (C = max changes per pair, at least 1; pad = int64 max),
-        chronological within each row.  Changes alternate, so pair
-        existence at t is ``base XOR (number of changes <= t) mod 2`` —
-        a compare-and-sum per row, the layout the whole-plan compiler
-        (repro.taf.compile) uploads once per operand and reuses for every
-        jitted dispatch.  ``n_events`` and ``n_changes`` count the pair
-        events the table was built from and those it keeps.
-        ``base``/``pair_center``/``pair_other`` ride along so a device
-        program can rebuild adjacency without touching the host table
-        again.
+        ``chg_t (n_pairs, C)`` is ``change_table`` over each pair's events:
+        a re-add of a live edge or a delete of a dead one cannot change
+        existence at any timepoint, so it goes, and existence at t is
+        ``base XOR (number of changes <= t) mod 2`` — the layout the
+        whole-plan compiler (repro.taf.compile) uploads once per operand
+        and reuses for every jitted dispatch.  ``n_events`` and
+        ``n_changes`` count the pair events the table was built from and
+        those it keeps.  ``base``/``pair_center``/``pair_other`` ride along
+        so a device program can rebuild adjacency without touching the
+        host table again.
         """
         cached = getattr(self, "_device_export", None)
         if cached is not None:
             return cached
         evm = self.seq >= 0
         p = self.pair_id[evm]
-        st = self.st[evm]
-        # state before each event: the previous event's, or the pair's base
-        prev = np.empty_like(st)
-        if len(p):
-            first = np.r_[True, p[1:] != p[:-1]]
-            prev[1:] = st[:-1]
-            prev[first] = self.base[p[first]]
-        chg = st != prev
-        pc = p[chg]
-        counts = (np.bincount(pc, minlength=self.n_pairs).astype(np.int64)
-                  if self.n_pairs else np.zeros(0, np.int64))
-        C = max(int(counts.max()) if len(counts) else 0, 1)
-        chg_t = np.full((self.n_pairs, C), np.iinfo(np.int64).max, np.int64)
-        if len(pc):
-            # (pair-major, chronological): column is the change's rank
-            # within its pair's run
-            col = np.arange(len(pc)) - np.r_[0, np.cumsum(counts)][pc]
-            chg_t[pc, col] = self.t[evm][chg]
+        chg_t, n_changes = change_table(p, self.st[evm], self.t[evm],
+                                        self.base, self.n_pairs)
         cached = {
-            "chg_t": chg_t, "n_events": len(p), "n_changes": len(pc),
+            "chg_t": chg_t, "n_events": len(p), "n_changes": n_changes,
             "base": self.base.astype(np.int8),
             "pair_center": self.pair_center.astype(np.int32),
             "pair_other": self.pair_other.copy(),

@@ -5,7 +5,7 @@ style="kernel" device-operand cache."""
 import numpy as np
 import pytest
 
-from repro.core.events import EDGE_ADD, EDGE_DEL, NODE_ADD, NODE_DEL
+from repro.core.events import EDGE_ADD, EDGE_DEL, NATTR_SET, NODE_ADD, NODE_DEL
 from repro.taf import TemporalQuery, compile as tc, replay
 from repro.taf.plan import PlanExecutor
 from repro.taf.son import SoTS
@@ -25,18 +25,19 @@ def _ts(rng, t_max=40, T=20):
     return np.sort(rng.randint(0, t_max + 1, size=T)).astype(np.int64)
 
 
-def _pair_sots(runs, adj=(), N=8, t_max=40):
-    """SoTS of N nodes (ids 0..N-1), all present, with no attributes,
-    whose only events are the edge events ``runs`` — (center, t, kind,
-    other), chronological per center — over the initial adjacency
-    ``adj``, a list of (center, other) pairs."""
+def _pair_sots(runs, adj=(), N=8, t_max=40, present=None):
+    """SoTS of N nodes (ids 0..N-1), present at t0 as ``present`` says
+    (all by default), with no attributes, whose events are ``runs`` —
+    (center, t, kind, other), chronological per center — over the initial
+    adjacency ``adj``, a list of (center, other) pairs."""
     runs = sorted(runs, key=lambda r: (r[0], r[1]))  # stable: same-second order kept
     center = np.array([r[0] for r in runs], np.int64)
     adj = sorted(adj)
     adj_c = np.array([a[0] for a in adj], np.int64)
     return SoTS(
         node_ids=np.arange(N, dtype=np.int32), t0=0, t1=t_max,
-        init_present=np.ones(N, np.int8),
+        init_present=(np.ones(N, np.int8) if present is None
+                      else np.asarray(present, np.int8)),
         init_attrs=np.zeros((N, 3), np.int32),
         ev_indptr=np.searchsorted(center, np.arange(N + 1)).astype(np.int64),
         ev_t=np.array([r[1] for r in runs], np.int64),
@@ -90,8 +91,30 @@ def _toggle_operand(rng, t_max=40):
     return _pair_sots(runs, adj=[(1, 0), (6, 4)], t_max=t_max), ts
 
 
+def _presence_operand(rng, t_max=40):
+    """Node events that do and do not change presence: a node that leaves
+    and returns in one second, then leaves; one that is added while
+    present, then leaves; an absent one added and deleted in one second;
+    a delete, then an attribute write that restores presence; a delete of
+    an absent node, an add, a re-add; a node with edge events alone and
+    one with no event.  Timepoints fall on every change's second."""
+    runs = [(0, 5, NODE_DEL, -1), (0, 5, NODE_ADD, -1), (0, 12, NODE_DEL, -1),
+            (1, 7, NODE_ADD, -1), (1, 7, NODE_DEL, -1),
+            (2, 6, NODE_ADD, -1), (2, 6, NODE_DEL, -1),
+            (3, 4, NODE_DEL, -1), (3, 9, NATTR_SET, -1),
+            (3, 15, NATTR_SET, -1),
+            (4, 3, NODE_DEL, -1), (4, 8, NODE_ADD, -1), (4, 10, NODE_ADD, -1),
+            (5, 4, EDGE_ADD, 6), (6, 4, EDGE_ADD, 5), (0, 2, EDGE_ADD, 3),
+            (3, 2, EDGE_ADD, 0), (1, 11, EDGE_ADD, 4), (4, 11, EDGE_ADD, 1)]
+    ts = np.sort(np.r_[3, 4, 5, 6, 7, 8, 9, 10, 12, 15,
+                       rng.randint(0, t_max + 1, 8)]).astype(np.int64)
+    return _pair_sots(runs, adj=[(1, 3), (3, 1)], t_max=t_max,
+                      present=[1, 1, 0, 1, 0, 1, 1, 0]), ts
+
+
 # parity operands beyond the random ones, by name
-SPECIAL = {"readd": _readd_operand, "toggle": _toggle_operand}
+SPECIAL = {"readd": _readd_operand, "toggle": _toggle_operand,
+           "presence": _presence_operand}
 
 
 def _operand(case, offset):
@@ -121,16 +144,33 @@ def _pair_changes(sots):
     return changes, n_events
 
 
+def _node_changes(sots):
+    """(presence changes per node row, NODE_DELs) by a plain replay of
+    each row's events."""
+    changes = np.zeros(len(sots), np.int64)
+    for i in range(len(sots)):
+        state = int(sots.init_present[i])
+        for j in range(sots.ev_indptr[i], sots.ev_indptr[i + 1]):
+            if sots.ev_kind[j] in (NODE_ADD, NODE_DEL, NATTR_SET):
+                new = int(sots.ev_kind[j] != NODE_DEL)
+                changes[i] += new != state
+                state = new
+    return changes, int((sots.ev_kind == NODE_DEL).sum())
+
+
 # ---------------------------------------------------------------------------
 # Randomized parity: fused == staged
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("seed", [*range(6), "presence"])
 def test_fused_slice_bit_identical_randomized(seed):
-    rng = np.random.RandomState(seed)
-    sots = random_sots(rng, N=rng.randint(3, 14))
-    ts = _ts(rng, T=rng.randint(tc.MIN_FUSE_T, 40))
+    if seed in SPECIAL:
+        sots, ts = SPECIAL[seed](np.random.RandomState(0))
+    else:
+        rng = np.random.RandomState(seed)
+        sots = random_sots(rng, N=rng.randint(3, 14))
+        ts = _ts(rng, T=rng.randint(tc.MIN_FUSE_T, 40))
     fused, staged = _both(TemporalQuery.over(sots).timeslice(list(ts)))
     assert any("fused slice" in n for n in fused.notes), fused.notes
     np.testing.assert_array_equal(fused.value["present"],
@@ -198,22 +238,47 @@ def test_edge_export_keeps_only_existence_changes(case):
                                   er.exist_matrix(ts))
 
 
+@pytest.mark.parametrize("case", [*range(6), *SPECIAL])
+def test_presence_from_change_table_matches_host_replay(case):
+    """Presence by the parity of each node's presence changes, on the host
+    table and in the device program, equals the host replay's presence
+    bit for bit; the table is as wide as the most changes of one node."""
+    import jax
+    import jax.numpy as jnp
+
+    sots, ts = _operand(case, 600)
+    want, _ = replay.state_at_many(sots, ts)
+    exp = replay.node_presence_changes(sots)
+    changes, _ = _node_changes(sots)
+    assert exp["pres_t"].shape == (len(sots), max(int(changes.max()), 1))
+    cnt = (exp["pres_t"][:, :, None] <= ts[None, None, :]).sum(axis=1)
+    np.testing.assert_array_equal(exp["base"][:, None] ^ (cnt & 1), want)
+    node = tc._node_arrays(sots)
+    assert set(node) == {"pres_t", "init_present"}
+    got = jax.jit(lambda n, t: tc._dev_presence(jnp, n, t))(node, tc._tsv(ts))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    if case == "presence":  # three changes of node 0; none of nodes 5-7
+        np.testing.assert_array_equal(changes, [3, 1, 2, 2, 1, 0, 0, 0])
+
+
 @pytest.mark.parametrize("case", ["deletes", *range(3), *SPECIAL])
 def test_node_export_counts_events_slots_and_deletes(case):
-    """The node operand's counters add the real events of its padded
-    table, the table's slots (N x the most events of one node) and the
-    NODE_DELs among the events, once per export."""
+    """The node operand's counters add the presence changes of its table,
+    the table's slots (N x the most changes of one node), every event of
+    the rows it read and the NODE_DELs among them, once per export."""
     if case == "deletes":  # node 0 leaves and returns, node 1 leaves
         sots = _pair_sots([(0, 5, NODE_DEL, -1), (0, 9, NODE_ADD, -1),
                            (1, 3, EDGE_ADD, 2), (2, 3, EDGE_ADD, 1),
                            (1, 7, NODE_DEL, -1), (1, 8, EDGE_DEL, 2)], N=4)
-        want = {"node_events": 6, "node_slots": 4 * 3, "node_deletes": 2}
+        want = {"node_events": 3, "node_slots": 4 * 2,
+                "node_events_seen": 6, "node_deletes": 2}
     else:
         sots, _ = _operand(case, 500)
-        per_node = np.diff(sots.ev_indptr)
-        want = {"node_events": len(sots.ev_t),
-                "node_slots": len(sots) * max(int(per_node.max()), 1),
-                "node_deletes": int((sots.ev_kind == NODE_DEL).sum())}
+        changes, n_deletes = _node_changes(sots)
+        want = {"node_events": int(changes.sum()),
+                "node_slots": len(sots) * max(int(changes.max()), 1),
+                "node_events_seen": len(sots.ev_t),
+                "node_deletes": n_deletes}
     before = dict(tc.STATS)
     tc._node_arrays(sots)
     tc._node_arrays(sots)  # served from the operand cache: counted once

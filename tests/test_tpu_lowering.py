@@ -9,6 +9,7 @@ process at a time may load the TPU compiler library.
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,12 +29,13 @@ from repro.taf import compile as tc
 H, P, S, K, T = 6, 8, 4096, 4, 16
 
 # chip_smoke.py's fused-analytics operand at 250k events, seed 0 (window
-# = last quarter): N members, Emax events per member row and pair-table
-# rows as the smoke prints them; existence changes per pair row and
+# = last quarter): N members and pair-table rows as the smoke prints
+# them; presence changes per member row as its history gives them (370
+# members change once, none twice); existence changes per pair row and
 # canonical edges (at most half the pair rows) are upper estimates; 64
 # timepoints
-SMOKE_N, SMOKE_EMAX, SMOKE_PAIRS, SMOKE_CHANGES, SMOKE_E, SMOKE_T = (
-    25_336, 374, 213_924, 4, 107_000, 64)
+SMOKE_N, SMOKE_NODE_CHANGES, SMOKE_PAIRS, SMOKE_CHANGES, SMOKE_E, SMOKE_T = (
+    25_336, 1, 213_924, 4, 107_000, 64)
 
 
 @pytest.fixture(scope="module")
@@ -91,14 +93,13 @@ def test_motif_kernel_lowers_at_largest_fused_n(spec):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _pagerank_program(spec, pairs, changes, edges):
-    """The fused PageRank program compiled over smoke-sized node rows and
-    an edge operand of the given pair rows, change columns and edges."""
+def _operands(spec, pairs, changes, edges):
+    """Shapes of the fused programs' (node, edge, timepoints) operands:
+    smoke-sized node rows and an edge operand of the given pair rows,
+    change columns and edges."""
     i32 = jnp.int32
-    node = {k: spec((SMOKE_N, SMOKE_EMAX), i32)
-            for k in ("ev_t", "ev_kind", "ev_key", "ev_val")}
-    node["init_present"] = spec((SMOKE_N,), i32)
-    node["init_attrs"] = spec((SMOKE_N, K), i32)
+    node = {"pres_t": spec((SMOKE_N, SMOKE_NODE_CHANGES), i32),
+            "init_present": spec((SMOKE_N,), i32)}
     edge = {"chg_t": spec((pairs, changes), i32),
             "base": spec((pairs,), i32),
             "edge_valid": spec((edges,), jnp.float32)}
@@ -106,8 +107,13 @@ def _pagerank_program(spec, pairs, changes, edges):
         edge[k] = spec((edges,), i32)
     for k in ("frow", "fcol", "feid"):
         edge[k] = spec((2 * edges,), i32)
+    return node, edge, spec((SMOKE_T,), i32)
+
+
+def _pagerank_program(spec, pairs, changes, edges):
+    """The fused PageRank program compiled for the described chip."""
     prog = tc._build_series_program(tc.pagerank())
-    return prog.lower(node, edge, spec((SMOKE_T,), i32)).compile()
+    return prog.lower(*_operands(spec, pairs, changes, edges)).compile()
 
 
 def test_fused_pagerank_program_lowers_at_smoke_shapes(spec):
@@ -117,10 +123,30 @@ def test_fused_pagerank_program_lowers_at_smoke_shapes(spec):
 
 def test_edge_liveness_fuses_at_a_wide_change_table(spec):
     """A deletion-heavy history gives a wide change table.  The per-row
-    compare-and-sum must fuse into its reduction: at C = 512 the program
+    compare and its parity reduction must fuse: at C = 512 the program
     holds less than one more (P, T) plane than at C = 1, where an
     unfused compare would hold P * C * T int32s (6.5 GB)."""
     pairs, changes, edges = 50_000, 512, 25_000
     temp = {c: _pagerank_program(spec, pairs, c, edges)
             .memory_analysis().temp_size_in_bytes for c in (1, changes)}
     assert temp[changes] - temp[1] < pairs * SMOKE_T * 4
+
+
+@pytest.mark.parametrize("build", [
+    lambda: tc._build_series_program(tc.pagerank()),
+    lambda: tc._build_series_program(tc.components()),
+    lambda: tc._build_evolution_program(tc.component_count()),
+    lambda: tc._build_evolution_program(tc.max_pagerank()),
+], ids=["pagerank", "components", "component_count", "max_pagerank"])
+def test_fused_programs_lower_without_a_loop(build):
+    """Presence and edge liveness are compare-and-sums over change tables,
+    and propagation rounds unroll: the programs hold no loop, so no
+    search over a padded event table can come back unseen.  Lowered for
+    this host, with no chip described.  (The triangle program is left
+    out: off the chip its kernel runs interpreted, as a loop over its
+    grid.)"""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    text = build().lower(*_operands(spec, 1_000, 2, 500)).as_text()
+    assert not re.search(r"\bwhile\b", text)
