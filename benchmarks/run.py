@@ -1096,33 +1096,6 @@ def table1_index_comparison():
              f"bytes={store.stats.bytes_written}")
 
 
-def bench_checkpoint_store():
-    """Beyond-paper: TGI checkpoint store — delta-vs-snapshot bytes and
-    restore latency vs parallel fetch (the LM-plane integration)."""
-    import jax
-
-    from repro.storage.checkpoint import CheckpointConfig, CheckpointStore
-    from repro.storage.kvstore import DeltaStore
-
-    rng = np.random.RandomState(0)
-    tree = {"w": rng.randn(512, 1024).astype(np.float32),
-            "m": rng.randn(512, 1024).astype(np.float32)}
-    store = CheckpointStore(DeltaStore(m=4, r=2, backend="mem"),
-                            CheckpointConfig(snapshot_every=4))
-    b_prev = 0
-    for s in range(8):
-        tree = jax.tree.map(
-            lambda x: x + rng.randn(*x.shape).astype(np.float32) * 1e-3, tree
-        )
-        store.save(s, tree)
-        b = store.store.stats.bytes_written
-        _row(f"ckpt/save{s}_{store.saves[-1]['kind']}", 0.0, f"bytes={b - b_prev}")
-        b_prev = b
-    for c in (1, 4):
-        us = _timeit(lambda: store.restore(step=7, c=c), repeat=2)
-        _row(f"ckpt/restore_c{c}", us)
-
-
 def bench_delta_overlay_kernel():
     """Kernel micro-bench: fused overlay (jit'd jnp mirror of the Pallas
     kernel) vs the numpy pairwise chain, h=2..8 (DESIGN §7 HBM argument)."""
@@ -1346,7 +1319,6 @@ BENCHES: Dict[str, Callable] = {
     "transport": bench_transport,
     "multiwriter": bench_multiwriter,
     "table1": table1_index_comparison,
-    "ckpt": bench_checkpoint_store,
     "kernel": bench_delta_overlay_kernel,
     "fusion": bench_fusion,
     "concurrency": bench_concurrency,

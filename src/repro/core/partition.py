@@ -9,13 +9,12 @@ The static partitioner is a streaming LDG-style greedy (BFS order,
 capacity-penalized neighbor affinity) followed by bounded
 Kernighan-Lin-style refinement sweeps; pure numpy, runs at timespan
 boundaries on the host (control plane — the TPU only consumes the
-resulting layout).  1-hop edge-cut replication (paper Fig. 5d) is
-computed here and stored as auxiliary micro-deltas by the TGI builder.
+resulting layout).  1-hop edge-cut replication (paper Fig. 5d) is the
+TGI builder's (``core/ingest.py``), stored as auxiliary micro-deltas.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Optional
 
 import numpy as np
 
@@ -91,10 +90,6 @@ def collapse(events: EventLog, omega: str = "union_max",
 # ---------------------------------------------------------------------------
 # Static partitioning
 # ---------------------------------------------------------------------------
-
-
-def edge_cut(src, dst, assign) -> int:
-    return int((assign[src] != assign[dst]).sum())
 
 
 def partition_graph(node_ids: np.ndarray, src: np.ndarray, dst: np.ndarray,
@@ -193,15 +188,3 @@ def partition_timespan(events: EventLog, n_parts: int, strategy: str = "hash",
     assign = partition_graph(nids, src, dst, w, n_parts, seed=seed)
     return nids, assign
 
-
-def replication_lists(src, dst, assign_of) -> Dict[int, np.ndarray]:
-    """1-hop edge-cut replication: for each partition p, the set of
-    *external* neighbor node-ids that its nodes connect to (stored as
-    auxiliary micro-deltas so snapshot/node reads are unaffected)."""
-    out: Dict[int, list] = {}
-    ps, pd = assign_of(src), assign_of(dst)
-    cut = ps != pd
-    for p in np.unique(np.concatenate([ps, pd])):
-        ext = np.concatenate([dst[cut & (ps == p)], src[cut & (pd == p)]])
-        out[int(p)] = np.unique(ext)
-    return {p: v for p, v in out.items()}

@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels of the store's device path: delta_overlay folds node
+# payload deltas for snapshot reads (core/snapshot.py, core/tgi.py);
+# temporal_motif counts per-node triangles for fused analytics
+# (taf/compile.py). Each is <name>.py + ops.py + ref.py.
